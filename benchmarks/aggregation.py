@@ -45,8 +45,8 @@ def bench():
     K, N = 8, 4_000_000
     stack = jnp.asarray(rng.standard_normal((K, N)), jnp.float32)
     w = jnp.asarray(rng.uniform(0.5, 1.5, K), jnp.float32)
-    for name, fn in (("kernel_interpret",
-                      lambda: fedavg_pallas(stack, w, interpret=True)),
+    for name, fn in (("kernel",
+                      lambda: fedavg_pallas(stack, w)),
                      ("jnp_ref", lambda: ref_flat(stack, w))):
         fn().block_until_ready()
         t0 = time.perf_counter()

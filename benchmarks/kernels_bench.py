@@ -1,9 +1,9 @@
 """Benchmark 6 — Pallas kernels vs jnp oracles.
 
-This container executes kernels in interpret mode (Python emulation of the
-TPU grid), so wall times here validate CORRECTNESS-path overhead only — the
-TPU is the performance target; roofline expectations are derived in
-EXPERIMENTS.md. Derived: max abs deviation vs the oracle.
+The federated-path kernels (fedavg, quantize, checksum) follow the
+platform: native on a TPU, the Pallas interpreter (Python emulation of the
+TPU grid) on a CPU, where wall times validate the correctness path only.
+Derived: max abs deviation vs the oracle.
 """
 
 from __future__ import annotations
@@ -44,21 +44,21 @@ def bench():
 
     stack = jnp.asarray(rng.standard_normal((4, 262_144)), jnp.float32)
     w = jnp.asarray([0.1, 0.2, 0.3, 0.4], jnp.float32)
-    us_k, out_k = _time(lambda: fedavg_pallas(stack, w, interpret=True))
+    us_k, out_k = _time(lambda: fedavg_pallas(stack, w))
     us_r, out_r = _time(lambda: fedavg_flat(stack, w))
     dev = float(jnp.abs(out_k - out_r).max())
     rows.append(("kernels/fedavg_pallas", us_k, f"max_dev={dev:.2e}"))
     rows.append(("kernels/fedavg_ref", us_r, "oracle"))
 
     x = jnp.asarray(rng.standard_normal((64, 1024)), jnp.float32)
-    us_k, (q_k, s_k) = _time(lambda: quantize_pallas(x, interpret=True))
+    us_k, (q_k, s_k) = _time(lambda: quantize_pallas(x))
     us_r, (q_r, s_r) = _time(lambda: quantize_blockwise(x))
     dev = float(jnp.abs(s_k - s_r).max())
     rows.append(("kernels/quantize_pallas", us_k, f"scale_dev={dev:.2e}"))
     rows.append(("kernels/quantize_ref", us_r, "oracle"))
 
     data = jnp.asarray(rng.integers(0, 256, 262_144).astype(np.int32))
-    us_k, c_k = _time(lambda: checksum_pallas(data, interpret=True))
+    us_k, c_k = _time(lambda: checksum_pallas(data))
     us_r, c_r = _time(lambda: chunksum32_jnp(data))
     rows.append(("kernels/checksum_pallas", us_k,
                  f"match={int(c_k) == int(c_r)}"))

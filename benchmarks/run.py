@@ -15,6 +15,7 @@ from benchmarks import (adaptive_bench, aggregation, async_vs_sync, codecs,
                         fl_convergence, fleet_scale, kernels_bench, roofline,
                         simcore, topology_bench, transport_comparison,
                         transport_scenarios, vmap_train, wire_bench)
+from repro.compile_cache import enable_compile_cache
 
 SUITES = {
     "simcore": simcore,
@@ -38,6 +39,7 @@ def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--only", default=None, choices=list(SUITES))
     args = ap.parse_args()
+    enable_compile_cache()
     suites = {args.only: SUITES[args.only]} if args.only else SUITES
     print("name,us_per_call,derived")
     for name, mod in suites.items():

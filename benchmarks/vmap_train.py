@@ -30,6 +30,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import (CohortSpec, FleetConfig, FLConfig, TransportConfig,
                         build_fleet_training)
 from repro.core.client_compute import make_model, make_train_backend
@@ -156,6 +157,7 @@ def main(argv=None) -> int:
                     help="fail unless both gates pass")
     ap.add_argument("--out", default=None)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     matrix = compute_matrix(args.clients, seed=args.seed,
                             budget_s=args.budget_s)

@@ -47,6 +47,7 @@ from __future__ import annotations
 
 import argparse
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import (FLConfig, FleetConfig, TransportConfig,
                         build_fleet_training, cohort_counts)
 
@@ -178,6 +179,7 @@ def main() -> None:
                          "a loss-driven compression/FEC ladder and prints "
                          "per-cohort renegotiation counts")
     args = ap.parse_args()
+    enable_compile_cache()
     modes = ["sync", "async"] if args.mode == "both" else [args.mode]
     if args.topology == "gossip":
         modes = ["sync"]   # gossip has no server to schedule async rounds

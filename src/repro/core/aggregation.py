@@ -31,22 +31,14 @@ def pairwise_average(server_tree: Any, client_tree: Any) -> Any:
         server_tree, client_tree)
 
 
-# Lazy probe for the Pallas fedavg kernel: None = not probed yet,
-# False = unavailable (no jax / no pallas), else the ops module.
-_KERNEL_OPS: Any = None
-
 FEDAVG_BACKENDS = ("numpy", "kernel", "auto")
 
 
 def _kernel_ops():
-    global _KERNEL_OPS
-    if _KERNEL_OPS is None:
-        try:
-            from repro.kernels.fedavg import ops as kernel_ops
-            _KERNEL_OPS = kernel_ops
-        except Exception:  # noqa: BLE001 - any import failure means "no kernel"
-            _KERNEL_OPS = False
-    return _KERNEL_OPS or None
+    # Imported on first use: the kernel module imports repro.core (for the
+    # flat-vector helpers), so a top-level import here would be circular.
+    from repro.kernels.fedavg import ops as kernel_ops
+    return kernel_ops
 
 
 def fedavg(trees: Sequence[Any], weights: Optional[Sequence[float]] = None,
@@ -58,16 +50,16 @@ def fedavg(trees: Sequence[Any], weights: Optional[Sequence[float]] = None,
     * ``"numpy"`` (default) — the per-leaf float32 accumulation below.
       Digest-stable: every replay test pins this path bit-for-bit.
     * ``"kernel"`` — the fused Pallas kernel
-      (``repro.kernels.fedavg.ops.fedavg_trees``); raises if jax/pallas is
-      not importable.
-    * ``"auto"`` — the kernel when jax is importable, numpy otherwise.
+      (``repro.kernels.fedavg.ops.fedavg_trees``): native on a TPU, the
+      Pallas interpreter elsewhere.
+    * ``"auto"`` — the kernel (JAX is a hard dependency).
 
     The two backends mirror each other to ~1 ULP
     (``tests/test_kernel_parity.py`` enforces the docstring claim) but are
     **not** bit-identical — the kernel reduces over clients in one fused
     pass while numpy accumulates sequentially — which is why the
-    orchestrator defaults to numpy: replay digests must not depend on
-    whether jax imports.
+    orchestrator defaults to numpy: replay digests must not depend on the
+    platform.
     """
     if not trees:
         raise ValueError("fedavg of zero clients")
@@ -75,13 +67,8 @@ def fedavg(trees: Sequence[Any], weights: Optional[Sequence[float]] = None,
         raise ValueError(f"unknown fedavg backend {backend!r}; "
                          f"one of {FEDAVG_BACKENDS}")
     if backend != "numpy":
-        ops = _kernel_ops()
-        if ops is not None:
-            ws = [1.0] * len(trees) if weights is None else list(weights)
-            return ops.fedavg_trees(trees, ws)
-        if backend == "kernel":
-            raise RuntimeError("fedavg backend='kernel' requested but the "
-                               "Pallas kernel is not importable (no jax?)")
+        ws = [1.0] * len(trees) if weights is None else list(weights)
+        return _kernel_ops().fedavg_trees(trees, ws)
     if weights is None:
         weights = [1.0] * len(trees)
     w = np.asarray(weights, dtype=np.float32)
@@ -119,14 +106,10 @@ def fedavg_stack(stack: np.ndarray,
         raise ValueError(f"unknown fedavg backend {backend!r}; "
                          f"one of {FEDAVG_BACKENDS}")
     if backend != "numpy":
-        ops = _kernel_ops()
-        if ops is not None:
-            ws = ([1.0] * stack.shape[0] if weights is None
-                  else [float(w) for w in weights])
-            return np.asarray(ops.fedavg_flat(stack, ws), dtype=np.float32)
-        if backend == "kernel":
-            raise RuntimeError("fedavg backend='kernel' requested but the "
-                               "Pallas kernel is not importable (no jax?)")
+        ws = ([1.0] * stack.shape[0] if weights is None
+              else [float(w) for w in weights])
+        return np.asarray(_kernel_ops().fedavg_flat(stack, ws),
+                          dtype=np.float32)
     if weights is None:
         weights = [1.0] * stack.shape[0]
     w = np.asarray(weights, dtype=np.float32)

@@ -54,7 +54,7 @@ class ClientModel(abc.ABC):
 
     Implementations expose the *same* local training step two ways, and
     ``tests/test_client_compute.py`` pins that they agree (bit-identical
-    for the python loop vs itself; ULP-bounded python-vs-vmap):
+    for the python loop vs itself; tolerance-bounded python-vs-vmap):
 
     * :meth:`train_fn` — ``(params_tree, round_idx, client) -> (tree,
       metrics)``, the historical per-client callable handed to
@@ -300,17 +300,16 @@ class ShardBackend(VmapBackend):
             return super()._batched(model)
         fn = self._sharded.get(id(model))
         if fn is None:
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import PartitionSpec as P
             from repro.distributed.fl_mesh import client_mesh
             mesh = client_mesh()
             vmapped = jax.vmap(model.jax_train)
             spec = P("clients")
-            fn = self._sharded[id(model)] = jax.jit(shard_map(
+            fn = self._sharded[id(model)] = jax.jit(jax.shard_map(
                 vmapped, mesh=mesh,
                 in_specs=(spec, spec, spec),
                 out_specs=(spec, spec),
-                check_rep=False))
+                check_vma=False))
         return fn
 
     def train(self, model, stack, client_idx, round_idx):

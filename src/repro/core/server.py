@@ -73,7 +73,7 @@ class FLConfig:
         default_factory=TransportConfig)
     aggregation: str = "fedavg"          # pairwise (paper Eq.1) | fedavg | trimmed_mean
     # fedavg implementation: numpy (default, digest-stable) | kernel
-    # (Pallas fedavg_trees; needs jax) | auto (kernel when importable).
+    # (Pallas fedavg kernel; native on a TPU) | auto (= kernel).
     aggregation_backend: str = "numpy"
     send_deltas: bool = False            # ship (trained - received) instead of weights
     error_feedback: bool = False         # residual compensation for lossy codecs

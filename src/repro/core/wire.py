@@ -95,6 +95,12 @@ class WireDecodeError(WireError):
     anything else propagates."""
 
 
+class WireKernelError(RuntimeError):
+    """A wire kernel failed on input its stage had already validated: a
+    fault of the device path, not of the payload.  Batch decode never
+    degrades around it to the per-item host path."""
+
+
 def _body_dtype_code(dtype: np.dtype) -> int:
     s = np.dtype(dtype).str.lstrip("=|")
     for i, d in enumerate(_BODY_DTYPES):
@@ -309,50 +315,43 @@ def set_batch_backend(name: str) -> str:
     through the Pallas kernels under ``repro.kernels`` — int8
     (de)quantize at the kernel's 1024 block via ``kernels/quantize``,
     top-k gather/scatter via ``kernels/topk`` — with parity pinned in
-    ``tests/test_kernel_parity.py``.  ``"auto"`` resolves to pallas when
-    the kernels import (JAX present), else numpy.  Stages whose params
-    fall outside a kernel's tile contract (e.g. ``int8(512)``) silently
-    keep the numpy path, so flipping the backend never changes bytes.
+    ``tests/test_kernel_parity.py``; the kernels lower natively on a TPU
+    and run in the Pallas interpreter elsewhere.  ``"auto"`` resolves to
+    pallas.  Stages whose params fall outside a kernel's tile contract
+    (e.g. ``int8(512)``) keep the numpy path.
     """
     global _BATCH_BACKEND
     if name not in WIRE_BATCH_BACKENDS:
         raise WireError(f"unknown batch backend {name!r}; choose from "
                         f"{WIRE_BATCH_BACKENDS}")
     if name == "auto":
-        name = "pallas" if (_topk_kernel_ops() is not None
-                            and _quantize_kernel_ops() is not None) \
-            else "numpy"
+        name = "pallas"
     prev = _BATCH_BACKEND
     _BATCH_BACKEND = name
     return prev
 
 
-_TOPK_OPS = None
-_QUANT_OPS = None
-
-
 def _topk_kernel_ops():
-    """``repro.kernels.topk.ops``, or None when JAX is unavailable."""
-    global _TOPK_OPS
-    if _TOPK_OPS is None:
-        try:
-            from repro.kernels.topk import ops
-            _TOPK_OPS = ops
-        except Exception:
-            _TOPK_OPS = False
-    return _TOPK_OPS or None
+    """``repro.kernels.topk.ops``, imported on first use (JAX is a hard
+    dependency; an import failure raises)."""
+    from repro.kernels.topk import ops
+    return ops
 
 
 def _quantize_kernel_ops():
-    """``repro.kernels.quantize.ops``, or None when JAX is unavailable."""
-    global _QUANT_OPS
-    if _QUANT_OPS is None:
-        try:
-            from repro.kernels.quantize import ops
-            _QUANT_OPS = ops
-        except Exception:
-            _QUANT_OPS = False
-    return _QUANT_OPS or None
+    """``repro.kernels.quantize.ops``, imported on first use."""
+    from repro.kernels.quantize import ops
+    return ops
+
+
+def _decode_kernel(fn, *args):
+    """Run a decode kernel, raising its failure as :class:`WireKernelError`
+    so the batch walk's malformed-payload wrapping cannot absorb it."""
+    try:
+        return np.asarray(fn(*args), dtype=np.float32)
+    except Exception as e:
+        raise WireKernelError(f"{fn.__name__} failed: "
+                              f"{type(e).__name__}: {e}") from e
 
 
 # --------------------------------------------------------------------------
@@ -552,9 +551,9 @@ class TopKStage(Stage):
             # sorting makes the byte layout identical.
             idx = np.sort(np.argpartition(np.abs(batch), -k, axis=1)[:, -k:],
                           axis=1).astype("<u4")
-            ops = _topk_kernel_ops() if _BATCH_BACKEND == "pallas" else None
-            if ops is not None:
-                vals = np.asarray(ops.topk_gather(batch, idx), dtype="<f4")
+            if _BATCH_BACKEND == "pallas":
+                vals = np.asarray(_topk_kernel_ops().topk_gather(batch, idx),
+                                  dtype="<f4")
             else:
                 vals = np.take_along_axis(batch, idx.astype(np.int64),
                                           axis=1)
@@ -595,10 +594,9 @@ class TopKStage(Stage):
             buf.reshape(n_items, 8 + 4 * k)[:, 8:]).view("<u4")
         if n == 0 or int(idx.max()) >= n:
             raise WireDecodeError("topk index out of range")
-        ops = _topk_kernel_ops() if _BATCH_BACKEND == "pallas" else None
-        if ops is not None:
-            return np.asarray(ops.topk_scatter(idx, vals, n),
-                              dtype=np.float32)
+        if _BATCH_BACKEND == "pallas":
+            return _decode_kernel(_topk_kernel_ops().topk_scatter, idx, vals,
+                                  n)
         out = np.zeros((n_items, n), dtype=np.float32)
         # Flat fancy assignment: duplicate indices resolve last-wins in
         # row-major order, exactly like the per-item out[idx] = vals.
@@ -705,8 +703,7 @@ class Int8Stage(Stage):
             scales = np.zeros((len(params), 0), dtype=np.float32)
         ops = _quantize_kernel_ops() if _BATCH_BACKEND == "pallas" else None
         if ops is not None and block == ops.QBLOCK and nb:
-            return np.asarray(ops.dequantize_matrix(q, scales, n),
-                              dtype=np.float32)
+            return _decode_kernel(ops.dequantize_matrix, q, scales, n)
         return dequantize_int8_batch(q, scales, n, block)
 
 
@@ -1429,7 +1426,7 @@ class Pipeline:
             slots = [{}] * n_items
             try:
                 arr = self.stages[j].decode_batch(arr, params_cols[j], slots)
-            except WireDecodeError:
+            except (WireDecodeError, WireKernelError):
                 raise
             except Exception as e:
                 raise WireDecodeError(

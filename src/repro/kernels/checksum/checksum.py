@@ -1,13 +1,14 @@
 """Pallas TPU kernel: ChunkSum-32 packet-payload checksum.
 
 Single pass over the payload: each grid step loads a (8, 1024) int32 tile,
-forms the weighted and unweighted partial sums on the VPU, and accumulates
-them into two scalar outputs (TPU grid steps execute sequentially, so
-read-modify-write on the output ref across steps is the standard
-accumulator pattern; step 0 initializes).
+forms the weighted and unweighted terms on the VPU, and adds them
+elementwise into a resident (2, 8, 1024) accumulator block (TPU grid steps
+execute sequentially, so read-modify-write on the output ref across steps
+is the standard accumulator pattern; step 0 initializes).  The wrapper
+folds the accumulator to the two sums.
 
 int32 wraparound is part of the checksum definition (see ref.py), so the
-adds are exact on any backend.
+adds are exact on any backend and in any order.
 """
 
 from __future__ import annotations
@@ -18,6 +19,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
 from repro.kernels.checksum.ref import WEIGHT_PERIOD
 
 TILE_R, TILE_C = 8, 1024
@@ -27,27 +29,24 @@ TILE = TILE_R * TILE_C
 def _checksum_kernel(x_ref, acc_ref):
     step = pl.program_id(0)
     x = x_ref[...]                                     # (8, 1024) int32
-    base = step * TILE
-    idx = base + (jax.lax.broadcasted_iota(jnp.int32, (TILE_R, TILE_C), 0)
-                  * TILE_C
-                  + jax.lax.broadcasted_iota(jnp.int32, (TILE_R, TILE_C), 1))
+    idx = step * TILE + (
+        jax.lax.broadcasted_iota(jnp.int32, (TILE_R, TILE_C), 0) * TILE_C
+        + jax.lax.broadcasted_iota(jnp.int32, (TILE_R, TILE_C), 1))
     w = (idx % WEIGHT_PERIOD) + 1
-    a_part = jnp.sum(x, dtype=jnp.int32)
-    b_part = jnp.sum(w * x, dtype=jnp.int32)
 
     @pl.when(step == 0)
     def _init():
-        acc_ref[0] = a_part
-        acc_ref[1] = b_part
+        acc_ref[0] = x
+        acc_ref[1] = w * x
 
     @pl.when(step != 0)
     def _acc():
-        acc_ref[0] = acc_ref[0] + a_part
-        acc_ref[1] = acc_ref[1] + b_part
+        acc_ref[0] += x
+        acc_ref[1] += w * x
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def checksum_pallas(x_i32: jax.Array, *, interpret: bool = True
+def checksum_pallas(x_i32: jax.Array, *, interpret: bool | None = None
                     ) -> jax.Array:
     """x_i32: (N,) int32 byte values -> uint32-style checksum as int32.
 
@@ -63,8 +62,9 @@ def checksum_pallas(x_i32: jax.Array, *, interpret: bool = True
         _checksum_kernel,
         grid=(tiles,),
         in_specs=[pl.BlockSpec((TILE_R, TILE_C), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((2,), lambda i: (0,)),
-        out_shape=jax.ShapeDtypeStruct((2,), jnp.int32),
-        interpret=interpret,
+        out_specs=pl.BlockSpec((2, TILE_R, TILE_C), lambda i: (0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((2, TILE_R, TILE_C), jnp.int32),
+        interpret=resolve_interpret(interpret),
     )(x_i32.reshape(tiles * TILE_R, TILE_C))
-    return (acc[0] & 0xFFFF) | ((acc[1] & 0xFFFF) << 16)
+    a, b = jnp.sum(acc, axis=(1, 2), dtype=jnp.int32)
+    return (a & 0xFFFF) | ((b & 0xFFFF) << 16)

@@ -9,7 +9,7 @@ from repro.kernels.checksum.checksum import checksum_pallas
 from repro.kernels.checksum.ref import chunksum32_np
 
 
-def checksum_bytes(data: bytes, *, interpret: bool = True) -> int:
+def checksum_bytes(data: bytes, *, interpret: bool | None = None) -> int:
     x = jnp.asarray(np.frombuffer(data, dtype=np.uint8).astype(np.int32))
     return int(np.uint32(np.asarray(checksum_pallas(x, interpret=interpret))))
 

@@ -9,7 +9,7 @@ from repro.kernels.quantize.quantize import (QBLOCK, dequantize_pallas,
                                              quantize_pallas)
 
 
-def quantize_vector(vec, *, interpret: bool = True):
+def quantize_vector(vec, *, interpret: bool | None = None):
     """Flat f32 vector -> (q int8 (padded to QBLOCK), scales, n)."""
     vec = jnp.asarray(vec, jnp.float32)
     n = vec.shape[0]
@@ -19,12 +19,12 @@ def quantize_vector(vec, *, interpret: bool = True):
     return q, s, n
 
 
-def dequantize_vector(q, scales, n, *, interpret: bool = True):
+def dequantize_vector(q, scales, n, *, interpret: bool | None = None):
     out = dequantize_pallas(q, scales, interpret=interpret)
     return out.reshape(-1)[:n]
 
 
-def quantize_matrix(mat, *, interpret: bool = True):
+def quantize_matrix(mat, *, interpret: bool | None = None):
     """Batched client slab: (N, P) f32 -> (q int8 (N, nb*QBLOCK),
     scales (N, nb)) — the wire ``int8`` stage's batch layout.  Rows are
     independent, so this is one kernel launch over N*nb blocks instead of
@@ -38,7 +38,7 @@ def quantize_matrix(mat, *, interpret: bool = True):
     return q.reshape(n_items, nb * QBLOCK), s.reshape(n_items, nb)
 
 
-def dequantize_matrix(q, scales, n, *, interpret: bool = True):
+def dequantize_matrix(q, scales, n, *, interpret: bool | None = None):
     """Inverse of :func:`quantize_matrix`: -> (N, n) f32."""
     scales = jnp.asarray(scales, jnp.float32)
     n_items, nb = scales.shape

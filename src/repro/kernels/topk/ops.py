@@ -13,14 +13,14 @@ import jax.numpy as jnp
 from repro.kernels.topk.topk import topk_gather_pallas, topk_scatter_pallas
 
 
-def topk_gather(batch, idx, *, interpret: bool = True):
+def topk_gather(batch, idx, *, interpret: bool | None = None):
     """batch: (N, P) f32, idx: (N, K) -> (N, K) f32 kept values."""
     return topk_gather_pallas(jnp.asarray(batch, jnp.float32),
                               jnp.asarray(idx).astype(jnp.int32),
                               interpret=interpret)
 
 
-def topk_scatter(idx, vals, n, *, interpret: bool = True):
+def topk_scatter(idx, vals, n, *, interpret: bool | None = None):
     """idx/vals: (N, K) -> dense (N, n) f32 (zeros off the kept set)."""
     return topk_scatter_pallas(jnp.asarray(idx).astype(jnp.int32),
                                jnp.asarray(vals, jnp.float32),

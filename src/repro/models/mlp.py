@@ -9,9 +9,9 @@ train backends batch every client of a round into a single compiled call.
 
 The legacy per-client path (:meth:`MnistMLPModel.train_fn`) runs the *same*
 jitted function unbatched, so python-vs-vmap parity is jax-vs-jax and
-ULP-bounded (pinned in ``tests/test_client_compute.py``); the data and
-minibatch schedule are keyed only by ``(seed, client_idx, round_idx)``,
-never by call order.
+bounded by a stated tolerance (pinned in ``tests/test_client_compute.py``);
+the data and minibatch schedule are keyed only by ``(seed, client_idx,
+round_idx)``, never by call order.
 """
 
 from __future__ import annotations

@@ -97,7 +97,10 @@ class TestFedavgStackParity:
         for key in tree_out:
             np.testing.assert_array_equal(tree_out[key], rebuilt[key])
 
-    @pytest.mark.parametrize("k,n", [(2, 256), (7, 4096), (16, 16384 + 5)])
+    # (300, 3000): more clients than one kernel block, so the K-tiled
+    # accumulation path runs.
+    @pytest.mark.parametrize("k,n", [(2, 256), (7, 4096), (16, 16384 + 5),
+                                     (300, 3000)])
     def test_kernel_mirrors_numpy_stack(self, k, n):
         rng = np.random.default_rng(k * 97 + n)
         stack = rng.standard_normal((k, n)).astype(np.float32)
@@ -192,7 +195,7 @@ class TestTopKKernelParity:
     contract for ``topk`` stages."""
 
     @pytest.mark.parametrize("n_items,p,k", [(1, 64, 4), (7, 1000, 50),
-                                             (16, 4096, 41)])
+                                             (16, 4096, 41), (3, 3000, 1500)])
     def test_gather_exact(self, n_items, p, k):
         rng = np.random.default_rng(n_items * 131 + p)
         batch = rng.standard_normal((n_items, p)).astype(np.float32)
@@ -205,7 +208,7 @@ class TestTopKKernelParity:
                 jax.numpy.asarray(batch), jax.numpy.asarray(idx))))
 
     @pytest.mark.parametrize("n_items,p,k", [(1, 64, 4), (7, 1000, 50),
-                                             (16, 4096, 41)])
+                                             (16, 4096, 41), (3, 3000, 1500)])
     def test_scatter_exact(self, n_items, p, k):
         rng = np.random.default_rng(n_items * 17 + p)
         idx = _unique_idx(rng, n_items, p, k)
@@ -337,6 +340,29 @@ class TestPallasWireBackend:
         max_scale = max(np.abs(v).max() for v in batch) / 127.0
         np.testing.assert_allclose(pallas_dec, numpy_dec,
                                    atol=1.01 * max_scale, rtol=0)
+
+    @pytest.mark.parametrize("spec,module,fn", [
+        ("topk(0.05)", "topk", "topk_scatter"),
+        ("int8(1024)", "quantize", "dequantize_matrix")])
+    def test_kernel_fault_is_not_degraded(self, spec, module, fn,
+                                          pallas_backend, monkeypatch):
+        """A decode kernel that fails on valid payloads is a device fault:
+        batch decode raises it instead of degrading every payload to the
+        per-item host path."""
+        import importlib
+
+        from repro.core import wire
+        pipeline = wire.parse_pipeline(spec)
+        rng = np.random.default_rng(23)
+        datas = pipeline.encode_batch(
+            [rng.standard_normal(2000).astype(np.float32) for _ in range(3)])
+
+        def broken(*args, **kwargs):
+            raise RuntimeError("device lost")
+        monkeypatch.setattr(
+            importlib.import_module(f"repro.kernels.{module}.ops"), fn, broken)
+        with pytest.raises(wire.WireKernelError, match="device lost"):
+            wire.decode_payload_batch(datas)
 
     def test_default_backend_unaffected_by_kernel_availability(self):
         from repro.core import wire

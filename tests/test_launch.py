@@ -11,6 +11,11 @@ import pytest
 from repro.launch.hlo_cost import analyze_hlo_text
 from repro.launch.lowering import xla_cost_dict
 
+# Children compile on the CPU backend only: on a host with a chip, a child
+# that loads the TPU library would contend with this process for it.
+CPU_CHILD_ENV = {"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
+                 "JAX_PLATFORMS": "cpu"}
+
 
 class TestHloCost:
     def test_matmul_flops_match_xla(self):
@@ -49,23 +54,14 @@ class TestHloCost:
         code = textwrap.dedent("""
             import os
             os.environ['XLA_FLAGS']='--xla_force_host_platform_device_count=4'
-            import inspect
             import jax, jax.numpy as jnp
             from jax.sharding import PartitionSpec as P
             from repro.launch.hlo_cost import analyze_hlo_text
-            # jax.shard_map landed after 0.4.x; the replication-check kwarg
-            # was renamed check_rep -> check_vma along the way.
-            shard_map = getattr(jax, 'shard_map', None)
-            if shard_map is None:
-                from jax.experimental.shard_map import shard_map
-            params = inspect.signature(shard_map).parameters
-            kw = ({'check_vma': False} if 'check_vma' in params
-                  else {'check_rep': False})
             mesh = jax.make_mesh((4,), ('x',))
             def f(a):
-                return shard_map(lambda v: jax.lax.psum(v, 'x'),
-                                 mesh=mesh, in_specs=P('x'),
-                                 out_specs=P(), **kw)(a)
+                return jax.shard_map(lambda v: jax.lax.psum(v, 'x'),
+                                     mesh=mesh, in_specs=P('x'),
+                                     out_specs=P(), check_vma=False)(a)
             a = jax.ShapeDtypeStruct((4, 256), jnp.float32)
             c = jax.jit(f).lower(a).compile()
             cost = analyze_hlo_text(c.as_text())
@@ -75,8 +71,7 @@ class TestHloCost:
             print('OK', cost.collective_bytes)
         """)
         r = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                           text=True, env={"PYTHONPATH": "src",
-                                           "PATH": "/usr/bin:/bin"})
+                           text=True, env=CPU_CHILD_ENV)
         assert "OK" in r.stdout, r.stderr[-1500:]
 
 
@@ -98,7 +93,8 @@ class TestDryrunPlumbing:
             cfg = dataclasses.replace(smoke_variant(get_config('yi-9b')),
                                       dtype='bfloat16')
             shape = ShapeConfig('t', 64, 8, 'train')
-            mesh = jax.make_mesh((2, 2), ('data', 'model'))
+            mesh = jax.make_mesh((2, 2), ('data', 'model'),
+                                 axis_types=(jax.sharding.AxisType.Auto,) * 2)
             rules = sh.rules_for(cfg, shape, mesh)
             with sh.use_mesh(mesh, rules):
                 fn, args = _build_lowerable(
@@ -116,7 +112,7 @@ class TestDryrunPlumbing:
         # takes minutes on a share-throttled CPU, and 300s proved flaky.
         r = subprocess.run([sys.executable, "-c", code], capture_output=True,
                            text=True, timeout=1200,
-                           env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin"})
+                           env=CPU_CHILD_ENV)
         assert "OK" in r.stdout, (r.stdout[-500:], r.stderr[-1500:])
 
     def test_rules_divisibility_fallbacks(self):
@@ -128,7 +124,8 @@ class TestDryrunPlumbing:
             import jax
             from repro.configs import get_config, SHAPES
             from repro.distributed import sharding as sh
-            mesh = jax.make_mesh((2, 2), ('data', 'model'))
+            mesh = jax.make_mesh((2, 2), ('data', 'model'),
+                                 axis_types=(jax.sharding.AxisType.Auto,) * 2)
             for arch, heads_dropped in (('hymba-1.5b', False),
                                         ('whisper-tiny', True)):
                 cfg = get_config(arch)
@@ -141,8 +138,7 @@ class TestDryrunPlumbing:
             print('OK')
         """)
         r = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                           text=True, env={"PYTHONPATH": "src",
-                                           "PATH": "/usr/bin:/bin"})
+                           text=True, env=CPU_CHILD_ENV)
         assert "OK" in r.stdout, r.stderr[-1500:]
 
     def test_skip_policy(self):

@@ -111,7 +111,8 @@ class TestFlMeshAggregation:
             import jax, jax.numpy as jnp, numpy as np
             from repro.distributed import fl_mesh as F
             from repro.distributed import sharding as sh
-            mesh = jax.make_mesh((2,2,2), ('pod','data','model'))
+            mesh = jax.make_mesh((2,2,2), ('pod','data','model'),
+                                 axis_types=(jax.sharding.AxisType.Auto,) * 3)
             rules = dict(sh.TRAIN_RULES); rules['fl_pod']='pod'
             with sh.use_mesh(mesh, rules):
                 x = {'w': jnp.stack([jnp.full((4,8), 1.0),
@@ -126,7 +127,8 @@ class TestFlMeshAggregation:
         """)
         r = subprocess.run([sys.executable, "-c", code], capture_output=True,
                            text=True, env={"PYTHONPATH": "src",
-                                           "PATH": "/usr/bin:/bin"})
+                                           "PATH": "/usr/bin:/bin",
+                                           "JAX_PLATFORMS": "cpu"})
         assert "OK" in r.stdout, r.stderr[-2000:]
 
 
