@@ -60,22 +60,13 @@ def say(msg: str) -> None:
     print(msg, flush=True)
 
 
-class CompileClock:
-    """Sums XLA backend-compile time reported by ``jax.monitoring``, so
-    rounds can report the compile time spent inside them apart."""
-
-    EVENT = "/jax/core/compile/backend_compile_duration"
-
-    def __init__(self) -> None:
-        import jax
-        self.secs = 0.0
-        self.count = 0
-        jax.monitoring.register_event_duration_secs_listener(self._on)
-
-    def _on(self, event: str, duration: float, **_kw) -> None:
-        if event == self.EVENT:
-            self.secs += duration
-            self.count += 1
+def compiles() -> tuple[int, float]:
+    """XLA compiles so far and their seconds, from the program's counters
+    ``jax.compiles`` and ``jax.compile_ns``, so rounds can report the
+    compile time spent inside them apart."""
+    from repro.core import tracing
+    c = tracing.counters()
+    return c.get("jax.compiles", 0), c.get("jax.compile_ns", 0) / NS
 
 
 # --------------------------------------------------------------------------
@@ -149,7 +140,7 @@ def build(train_backend: str, on_device: bool):
 
 
 def run_fleet(label: str, train_backend: str, on_device: bool,
-              clock: CompileClock, check_kernels: bool = False) -> dict:
+              check_kernels: bool = False) -> dict:
     """Build, warm up and run ROUNDS rounds; returns what the checks read.
     ``check_kernels`` lowers every kernel at the model's width first."""
     import numpy as np
@@ -157,7 +148,7 @@ def run_fleet(label: str, train_backend: str, on_device: bool,
     from repro.core import flatten_to_vector, wire
 
     t0 = time.perf_counter()
-    c0 = clock.secs
+    c0 = compiles()[1]
     fb = build(train_backend, on_device)
     model = fb.model
     acc0 = model.accuracy(fb.system.global_params)
@@ -169,13 +160,13 @@ def run_fleet(label: str, train_backend: str, on_device: bool,
             np.arange(N_CLIENTS, dtype=np.int32),
             np.zeros(N_CLIENTS, np.int32))
     say(f"[{label}] setup_s={time.perf_counter() - t0:.3f} "
-        f"(compile_s={clock.secs - c0:.3f}) n_params={model.n_params} "
+        f"(compile_s={compiles()[1] - c0:.3f}) n_params={model.n_params} "
         f"wire_backend={wire.batch_backend()} acc_init={acc0:.4f}")
     if check_kernels:
         check_native_lowering(N_CLIENTS, model.n_params)
     rounds = []
     for _ in range(ROUNDS):
-        t1, c1 = time.perf_counter(), clock.secs
+        t1, c1 = time.perf_counter(), compiles()[1]
         res = fb.system.run_round()
         wall = time.perf_counter() - t1
         acc = model.accuracy(fb.system.global_params)
@@ -184,7 +175,7 @@ def run_fleet(label: str, train_backend: str, on_device: bool,
             f"arrived={len(res.arrived)} failed={len(res.failed)} "
             f"bytes={res.bytes_sent} packets={res.packets_sent} "
             f"duration_ns={res.duration_ns} acc={acc:.4f} "
-            f"wall_s={wall:.3f} (compile_s={clock.secs - c1:.3f})")
+            f"wall_s={wall:.3f} (compile_s={compiles()[1] - c1:.3f})")
     if fb.trainer is not None:
         say(f"[{label}] training flush sizes {fb.trainer.batch_sizes}")
     return {"rounds": rounds, "acc0": acc0, "acc": acc, "build": fb,
@@ -271,11 +262,10 @@ def check_wire_encode(fb) -> list[str]:
     return fails
 
 
-def smoke_one_chip(clock: CompileClock) -> list[str]:
-    dev = run_fleet("device", "vmap", on_device=True, clock=clock,
-                    check_kernels=True)
+def smoke_one_chip() -> list[str]:
+    dev = run_fleet("device", "vmap", on_device=True, check_kernels=True)
     fails = check_wire_encode(dev["build"])
-    ref = run_fleet("reference", "python", on_device=False, clock=clock)
+    ref = run_fleet("reference", "python", on_device=False)
     fails += compare("device vs reference", dev, ref)
     rose = dev["acc"] > dev["acc0"]
     say(f"[device] accuracy {dev['acc0']:.4f} -> {dev['acc']:.4f} "
@@ -286,9 +276,9 @@ def smoke_one_chip(clock: CompileClock) -> list[str]:
     return fails
 
 
-def smoke_four_chips(clock: CompileClock) -> list[str]:
-    shard = run_fleet("shard x4", "shard", on_device=True, clock=clock)
-    vmap = run_fleet("vmap x1", "vmap", on_device=True, clock=clock)
+def smoke_four_chips() -> list[str]:
+    shard = run_fleet("shard x4", "shard", on_device=True)
+    vmap = run_fleet("vmap x1", "vmap", on_device=True)
     return compare("shard vs vmap", shard, vmap)
 
 
@@ -330,16 +320,15 @@ def main(argv=None) -> int:
         return 1
     say(f"compile cache: {enable_compile_cache()}")
 
-    clock = CompileClock()
     t0 = time.perf_counter()
     try:
-        fails = (smoke_four_chips(clock) if args.chips == 4
-                 else smoke_one_chip(clock))
+        fails = smoke_four_chips() if args.chips == 4 else smoke_one_chip()
     except Exception:  # noqa: BLE001 - any phase failure fails the smoke
         traceback.print_exc()
         return 1
+    n_compiles, compile_s = compiles()
     say(f"total_s={time.perf_counter() - t0:.3f} "
-        f"compile_s={clock.secs:.3f} compiles={clock.count}")
+        f"compile_s={compile_s:.3f} compiles={n_compiles}")
     if fails:
         for f in fails:
             print(f"chip_smoke: FAIL {f}", file=sys.stderr)

@@ -18,6 +18,8 @@ from typing import Any, Optional, Sequence
 import jax
 import numpy as np
 
+from repro.core import tracing
+
 
 def pairwise_average(server_tree: Any, client_tree: Any) -> Any:
     """Paper Eq. (1): AggregatedParameters = (Client + Server) / 2.
@@ -83,6 +85,7 @@ def fedavg(trees: Sequence[Any], weights: Optional[Sequence[float]] = None,
     return jax.tree_util.tree_map(_avg, *trees)
 
 
+@tracing.span("aggregate.fedavg")
 def fedavg_stack(stack: np.ndarray,
                  weights: Optional[Sequence[float]] = None,
                  backend: str = "numpy") -> np.ndarray:
@@ -108,8 +111,11 @@ def fedavg_stack(stack: np.ndarray,
     if backend != "numpy":
         ws = ([1.0] * stack.shape[0] if weights is None
               else [float(w) for w in weights])
-        return np.asarray(_kernel_ops().fedavg_flat(stack, ws),
-                          dtype=np.float32)
+        out = np.asarray(_kernel_ops().fedavg_flat(stack, ws),
+                         dtype=np.float32)
+        tracing.count("device.h2d_bytes", stack.nbytes + 4 * len(ws))
+        tracing.count("device.d2h_bytes", out.nbytes)
+        return out
     if weights is None:
         weights = [1.0] * stack.shape[0]
     w = np.asarray(weights, dtype=np.float32)

@@ -43,6 +43,7 @@ from typing import Any, Callable, Optional
 
 import numpy as np
 
+from repro.core import tracing
 from repro.core.packetizer import flatten_to_vector, unflatten_from_vector
 
 
@@ -241,6 +242,9 @@ def _next_pow2(k: int) -> int:
     return 1 << max(0, (k - 1).bit_length())
 
 
+_STEP_SPAN = tracing.span("train.step")
+
+
 class VmapBackend(TrainBackend):
     """One ``jax.jit(jax.vmap(model.jax_train))`` call per flush.
 
@@ -262,7 +266,6 @@ class VmapBackend(TrainBackend):
         return fn
 
     def train(self, model, stack, client_idx, round_idx):
-        import jax.numpy as jnp
         k = stack.shape[0]
         kp = _next_pow2(k)
         if kp != k:
@@ -272,12 +275,28 @@ class VmapBackend(TrainBackend):
                 [client_idx, np.repeat(client_idx[-1:], pad)])
             round_idx = np.concatenate(
                 [round_idx, np.repeat(round_idx[-1:], pad)])
-        new, aux = self._batched(model)(
-            jnp.asarray(stack, jnp.float32),
-            jnp.asarray(client_idx, jnp.int32),
-            jnp.asarray(round_idx, jnp.int32))
-        out = np.asarray(new, np.float32)[:k]
-        return out, _aux_to_rows(aux, k)
+        return self._step(model, stack, client_idx, round_idx, k)
+
+    def _step(self, model, stack, client_idx, round_idx, k: int):
+        """The jitted call on a padded batch whose first ``k`` rows are
+        real: the ``train.step`` span, from the inputs' copy to the device
+        until the trained stack is numpy, and the rows and bytes it moved
+        (``train.rows``, ``train.pad_rows``, ``device.h2d_bytes``,
+        ``device.d2h_bytes``)."""
+        import jax.numpy as jnp
+        with _STEP_SPAN:
+            new, aux = self._batched(model)(
+                jnp.asarray(stack, jnp.float32),
+                jnp.asarray(client_idx, jnp.int32),
+                jnp.asarray(round_idx, jnp.int32))
+            new = np.asarray(new, np.float32)
+        tracing.count("train.rows", k)
+        tracing.count("train.pad_rows", stack.shape[0] - k)
+        tracing.count("device.h2d_bytes",
+                      4 * (stack.size + client_idx.size + round_idx.size))
+        tracing.count("device.d2h_bytes",
+                      new.nbytes + sum(v.nbytes for v in aux.values()))
+        return new[:k], _aux_to_rows(aux, k)
 
 
 class ShardBackend(VmapBackend):
@@ -329,12 +348,7 @@ class ShardBackend(VmapBackend):
                 [client_idx, np.repeat(client_idx[-1:], pad)])
             round_idx = np.concatenate(
                 [round_idx, np.repeat(round_idx[-1:], pad)])
-        import jax.numpy as jnp
-        new, aux = self._batched(model)(
-            jnp.asarray(stack, jnp.float32),
-            jnp.asarray(client_idx, jnp.int32),
-            jnp.asarray(round_idx, jnp.int32))
-        return np.asarray(new, np.float32)[:k], _aux_to_rows(aux, k)
+        return self._step(model, stack, client_idx, round_idx, k)
 
 
 _TRAIN_BACKENDS: dict[str, Callable[[], TrainBackend]] = {}
@@ -408,6 +422,7 @@ class BatchTrainer:
             raise KeyError(f"no model client index for {addr!r}") from None
         self._pending.append((key, params_tree, idx, int(round_idx)))
 
+    @tracing.span("train.flush")
     def flush(self) -> None:
         """Train every pending submission as one backend call."""
         if not self._pending:

@@ -21,6 +21,7 @@ from typing import Any, Optional, Sequence
 import jax
 import numpy as np
 
+from repro.core import tracing
 from repro.core.compression import Codec, RawCodec
 from repro.core.packets import HEADER_BYTES, Packet, make_data_packet
 from repro.core.wire import (Pipeline, PipelineState, WireError,
@@ -63,6 +64,7 @@ def num_params(tree: Any) -> int:
 # --------------------------------------------------------------------------
 # bytes <-> packets
 # --------------------------------------------------------------------------
+@tracing.span("packet.build")
 def packetize(data: bytes, addr: str, txn: int = 0,
               mtu: int = DEFAULT_MTU) -> list[Packet]:
     """Slice ``data`` into DATA packets with headers (X, Np, A), X=1..Np."""
@@ -70,6 +72,7 @@ def packetize(data: bytes, addr: str, txn: int = 0,
     if payload_max <= 0:
         raise ValueError("mtu too small")
     total = max(1, -(-len(data) // payload_max))
+    tracing.count("packets.built", total)
     return [
         make_data_packet(seq=i + 1, total=total, addr=addr, txn=txn,
                          payload=data[i * payload_max:(i + 1) * payload_max])
@@ -77,6 +80,7 @@ def packetize(data: bytes, addr: str, txn: int = 0,
     ]
 
 
+@tracing.span("packet.reassemble")
 def reassemble(packets: dict[int, Packet]) -> bytes:
     """Receiver §IV.B: 'Construct the original file from the packets.'"""
     if not packets:
@@ -128,6 +132,7 @@ class Packetizer:
                      state: Optional[PipelineState] = None) -> bytes:
         return self.pipeline.encode(flatten_to_vector(tree), state)
 
+    @tracing.span("wire.decode")
     def decode_bytes(self, data: bytes,
                      state: Optional[PipelineState] = None) -> np.ndarray:
         """Wire bytes -> flat float32 vector.  Self-describing payloads

@@ -210,9 +210,11 @@ class SyncScheduler:
     def _on_deadline(self) -> None:
         if self._round_open:
             sim = self.core.sim
-            sim.log(f"t={sim.now_ns}ns SERVER round "
-                    f"{self._round_idx} deadline -> straggler cutoff "
-                    f"({len(self._updates)}/{len(self._roster)} arrived)")
+            if sim.trace:
+                sim.log(f"t={sim.now_ns}ns SERVER round "
+                        f"{self._round_idx} deadline -> straggler cutoff "
+                        f"({len(self._updates)}/{len(self._roster)} "
+                        f"arrived)")
             self._finalize()
 
     def _finalize(self) -> None:

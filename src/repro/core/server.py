@@ -37,6 +37,7 @@ from typing import Any, Callable, Optional
 import numpy as np
 
 from repro.core import aggregation as agg
+from repro.core import tracing
 from repro.core.control import available_policies, make_policy
 from repro.core.packetizer import (Packetizer, flatten_to_vector, packetize,
                                    unflatten_from_vector)
@@ -183,6 +184,18 @@ class RoundResult:
     # Per-client telemetry snapshots ({addr: repro.core.telemetry.
     # ClientHealth}, sorted by addr) as of this window's end.
     client_health: dict = dataclasses.field(default_factory=dict)
+    # The window's host-side accounting (repro.core.tracing):
+    # ``spans`` = {name: (count, total_ns, self_ns)}, ``counters`` =
+    # {name: value}.  Init-only, kept as plain attributes and not as
+    # fields, so equality, ``asdict``, ``repr`` and every replay digest
+    # see the simulated outcome and never the host's time.
+    spans: dataclasses.InitVar[Optional[dict]] = None
+    counters: dataclasses.InitVar[Optional[dict]] = None
+
+    def __post_init__(self, spans: Optional[dict],
+                      counters: Optional[dict]) -> None:
+        self.spans = {} if spans is None else spans
+        self.counters = {} if counters is None else counters
 
 
 # --------------------------------------------------------------------------
@@ -981,6 +994,7 @@ class ServerCore:
         return folded, clamped
 
     # -- aggregation -----------------------------------------------------------
+    @tracing.span("aggregate")
     def apply_aggregation(self, contribs: list) -> None:
         """Fold ``[(flat vector, weight), ...]`` into the global model —
         the exact pre-refactor math, shared by every scheduling policy.
@@ -1035,12 +1049,18 @@ class ServerCore:
             raise ValueError(f"unknown aggregation {self.cfg.aggregation}")
 
     # -- result plumbing -------------------------------------------------------
-    def snapshot_stats(self) -> dict:
-        return dict(self.sim.stats)
+    def snapshot_stats(self) -> tuple[dict, tuple]:
+        """The simulator's counters and the tracer's totals, now: where
+        :meth:`stats_delta` measures a window from."""
+        return dict(self.sim.stats), tracing.snapshot()
 
-    def stats_delta(self, stats0: dict) -> dict:
+    def stats_delta(self, snap: tuple[dict, tuple]) -> dict:
+        """The ``RoundResult`` counts of the window since ``snap``."""
+        stats0, trace0 = snap
         s1 = self.sim.stats
+        spans, counters = tracing.delta(trace0)
         return {
+            "spans": spans, "counters": counters,
             "bytes_sent": s1["bytes_sent"] - stats0["bytes_sent"],
             "packets_sent": s1["packets_sent"] - stats0["packets_sent"],
             "packets_dropped": (s1["packets_dropped"]

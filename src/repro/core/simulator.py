@@ -44,6 +44,7 @@ from typing import Callable, Optional, Sequence
 
 import numpy as np
 
+from repro.core import tracing
 from repro.core.channel import Link, packet_key_arrays
 from repro.core.packets import Packet, PacketKind
 
@@ -347,6 +348,7 @@ class Simulator:
 
         self.schedule(arrival - self.now_ns, _deliver)
 
+    @tracing.span("engine.burst")
     def transmit_burst(self, src: Node, dst: Node,
                        pkts: Sequence[Packet]) -> None:
         """Transmit a back-to-back burst over one link.
@@ -440,6 +442,7 @@ class Simulator:
                        (flight.arrivals[0], flight.ties[0], flight))
 
     # -- the deep-ingestion pass (batched engine) ----------------------------
+    @tracing.span("engine.flight_pass")
     def _flight_pass(self, until_ns: Optional[int]) -> int:
         """Bulk-ingest every eligible pending flight packet below the next
         *effectful* point of the calendar; returns packets ingested.
@@ -569,8 +572,14 @@ class Simulator:
     # -- main loop -----------------------------------------------------------
     def run(self, until_ns: Optional[int] = None, max_events: int = 10_000_000
             ) -> int:
-        """Drain the calendar; returns the final simulation time."""
+        """Drain the calendar; returns the final simulation time.
+
+        Every event processed counts into ``events_processed`` and, by
+        kind, into the counters ``engine.events.timer`` (calendar events),
+        ``engine.events.packet`` (flight packets delivered one by one) and
+        ``engine.events.bulk`` (flight packets ingested in bulk)."""
         n = 0
+        n_packet = n_bulk = 0
         queue = self._queue
         flightq = self._flightq
         stats = self.stats
@@ -615,7 +624,9 @@ class Simulator:
                 i = fl.idx
                 if (not fl.bulk_dead and fl.refused_idx != i
                         and i < fl.safe_until and fl.dst._bulk0 is not None):
-                    n += self._flight_pass(until_ns)
+                    c = self._flight_pass(until_ns)
+                    n += c
+                    n_bulk += c
                     if n >= max_events:
                         raise _budget_error()
                     if fl.idx != i:
@@ -632,6 +643,7 @@ class Simulator:
                 fl.dst.deliver(pkt)
                 i += 1
                 n += 1
+                n_packet += 1
                 fl.idx = i
                 nf = len(fl.packets)
                 if fl.safe_until < i:
@@ -665,6 +677,9 @@ class Simulator:
             return self.now_ns
         finally:
             self.events_processed += n
+            tracing.count("engine.events.timer", n - n_packet - n_bulk)
+            tracing.count("engine.events.packet", n_packet)
+            tracing.count("engine.events.bulk", n_bulk)
 
     # -- replay digests ------------------------------------------------------
     def stats_digest(self) -> str:

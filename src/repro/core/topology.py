@@ -48,6 +48,7 @@ from typing import Any, Callable, Optional
 
 import numpy as np
 
+from repro.core import tracing
 from repro.core.packetizer import (flatten_to_vector, packetize,
                                    unflatten_from_vector)
 from repro.core.rounds import FederatedSystem, FLClient, FLConfig
@@ -704,6 +705,7 @@ class GossipSystem:
                              "wire transactions)")
         self._round_idx += 1
         stats0 = dict(self.sim.stats)
+        trace0 = tracing.snapshot()
         retx0 = self.retx_total
         self._failed_legs = 0
         t0 = self.sim.now_ns
@@ -735,6 +737,7 @@ class GossipSystem:
                 (num / den).astype(np.float32), self._template)
 
         s1 = self.sim.stats
+        spans, counters = tracing.delta(trace0)
         result = RoundResult(
             round_idx=self._round_idx,
             duration_ns=self.sim.now_ns - t0,
@@ -757,6 +760,7 @@ class GossipSystem:
                 "failed_legs": self._failed_legs,
                 "decode_errors": self.decode_errors,
             },
+            spans=spans, counters=counters,
         )
         self.history.append(result)
         if self.on_round_end is not None:

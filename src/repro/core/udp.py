@@ -11,6 +11,7 @@ from __future__ import annotations
 
 from typing import Callable, Optional
 
+from repro.core import tracing
 from repro.core.mudp import TxnStats, ingest_data_run
 from repro.core.packets import Packet, PacketKind
 from repro.core.simulator import Node, Simulator, Timer
@@ -116,6 +117,7 @@ class UdpReceiver:
             self.on_deliver(key[0], key[1], packets, total)
 
 
+@tracing.span("packet.reassemble")
 def reassemble_partial(packets: dict[int, Packet], total: int) -> bytes:
     """Best-effort reconstruction with zero-filled gaps (UDP baseline).
 

@@ -64,6 +64,7 @@ from typing import Callable, Optional, Sequence
 
 import numpy as np
 
+from repro.core import tracing
 from repro.core.compression import (MAX_DECODE_PARAMS, HexCodec, Int8Codec,
                                     RawCodec, TopKCodec, dequantize_int8,
                                     dequantize_int8_batch, quantize_int8,
@@ -344,11 +345,29 @@ def _quantize_kernel_ops():
     return ops
 
 
+_KERNEL_SPAN = tracing.span("wire.kernel")
+
+
+def _on_device(fn, *args):
+    """``fn(*args)`` for a kernel op, its result (or tuple of results)
+    back as numpy: the ``wire.kernel`` span from dispatch until the host
+    holds the result, with the host arrays in and the results out counted
+    as ``device.h2d_bytes`` / ``device.d2h_bytes``."""
+    with _KERNEL_SPAN:
+        out = fn(*args)
+        outs = tuple(np.asarray(o) for o in
+                     (out if isinstance(out, tuple) else (out,)))
+    tracing.count("device.h2d_bytes", sum(a.nbytes for a in args
+                                          if isinstance(a, np.ndarray)))
+    tracing.count("device.d2h_bytes", sum(o.nbytes for o in outs))
+    return outs if isinstance(out, tuple) else outs[0]
+
+
 def _decode_kernel(fn, *args):
     """Run a decode kernel, raising its failure as :class:`WireKernelError`
     so the batch walk's malformed-payload wrapping cannot absorb it."""
     try:
-        return np.asarray(fn(*args), dtype=np.float32)
+        return np.asarray(_on_device(fn, *args), dtype=np.float32)
     except Exception as e:
         raise WireKernelError(f"{fn.__name__} failed: "
                               f"{type(e).__name__}: {e}") from e
@@ -552,8 +571,9 @@ class TopKStage(Stage):
             idx = np.sort(np.argpartition(np.abs(batch), -k, axis=1)[:, -k:],
                           axis=1).astype("<u4")
             if _BATCH_BACKEND == "pallas":
-                vals = np.asarray(_topk_kernel_ops().topk_gather(batch, idx),
-                                  dtype="<f4")
+                vals = np.asarray(
+                    _on_device(_topk_kernel_ops().topk_gather, batch, idx),
+                    dtype="<f4")
             else:
                 vals = np.take_along_axis(batch, idx.astype(np.int64),
                                           axis=1)
@@ -658,7 +678,7 @@ class Int8Stage(Stage):
         n_items, n = batch.shape
         ops = _quantize_kernel_ops() if _BATCH_BACKEND == "pallas" else None
         if ops is not None and self.block == ops.QBLOCK and n:
-            q, scales = ops.quantize_matrix(batch)
+            q, scales = _on_device(ops.quantize_matrix, batch)
             q = np.asarray(q, dtype=np.int8)
             scales = np.asarray(scales, dtype=np.float32)
         else:
@@ -1194,6 +1214,7 @@ class Pipeline:
         return state
 
     # -- encode ---------------------------------------------------------------
+    @tracing.span("wire.encode")
     def encode(self, vec: np.ndarray,
                state: Optional[PipelineState] = None) -> bytes:
         """flat float32 vector -> wire bytes (headered unless legacy)."""
@@ -1222,6 +1243,7 @@ class Pipeline:
         header = WireHeader(self.spec, params, _body_dtype_code(arr.dtype))
         return header.pack() + np.ascontiguousarray(arr).tobytes()
 
+    @tracing.span("wire.encode")
     def encode_batch(self, vecs: Sequence[np.ndarray],
                      states: Optional[Sequence[Optional[PipelineState]]]
                      = None) -> list[bytes]:
@@ -1618,6 +1640,7 @@ def decode_payload(data: bytes,
     return vec, pipeline
 
 
+@tracing.span("wire.decode_batch")
 def decode_payload_batch(datas: Sequence[bytes]) -> list[
         tuple[Optional[np.ndarray], Optional[Pipeline],
               Optional[WireDecodeError]]]:
