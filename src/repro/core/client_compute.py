@@ -42,6 +42,7 @@ import abc
 from typing import Any, Callable, Optional
 
 import numpy as np
+from jax import tree_util
 
 from repro.core import tracing
 from repro.core.packetizer import flatten_to_vector, unflatten_from_vector
@@ -182,15 +183,53 @@ class TrainBackend(abc.ABC):
     steps as a stacked float32 matrix ``(K, n_params)`` plus int32 vectors
     of client indices and round numbers, and returns ``(new_stack,
     metrics)`` where ``metrics`` is one dict per row.
+
+    A caller may stage its rows in the backend's own reused buffer
+    instead of stacking them: ``staging(K, n_params)`` hands out the
+    buffer's first K rows, with at least ``padded_rows(K)`` rows behind
+    them, and ``train`` on exactly those rows runs the padded step over
+    the buffer in place.  Padding rows hold whatever the buffer last held;
+    rows are independent, so their contents never reach a real row.
     """
 
     name: str = "abstract"
+    _stage: Optional[np.ndarray] = None
 
     @abc.abstractmethod
     def train(self, model: ClientModel, stack: np.ndarray,
               client_idx: np.ndarray, round_idx: np.ndarray
               ) -> tuple[np.ndarray, list[dict]]:
         ...
+
+    def padded_rows(self, k: int) -> int:
+        """Rows the step runs for a batch of ``k``."""
+        return k
+
+    def staging(self, k: int, n_params: int) -> np.ndarray:
+        """The first ``k`` rows of the reused float32 staging buffer.
+
+        The buffer is allocated on first use and grown by doubling to
+        ``padded_rows(k)``, never shrunk; each (re)allocation counts
+        ``train.stage_allocs``."""
+        rows = self.padded_rows(k)
+        buf = self._stage
+        if buf is None or buf.shape[1] != n_params:
+            cap = rows
+        elif buf.shape[0] < rows:
+            cap = max(rows, 2 * buf.shape[0])
+        else:
+            return buf[:k]
+        buf = self._stage = np.zeros((cap, n_params), np.float32)
+        tracing.count("train.stage_allocs")
+        return buf[:k]
+
+    def _is_staged(self, stack: np.ndarray) -> bool:
+        """Whether ``stack`` is the leading rows of the staging buffer."""
+        buf = self._stage
+        return (buf is not None and stack.base is buf
+                and stack.strides == buf.strides
+                and stack.shape[1] == buf.shape[1]
+                and stack.ctypes.data == buf.ctypes.data)
 
 
 class PythonLoopBackend(TrainBackend):
@@ -230,12 +269,10 @@ class PythonLoopBackend(TrainBackend):
 
 
 def _aux_to_rows(aux: dict, k: int) -> list[dict]:
-    """Split a dict of (K,)-arrays into K per-row metric dicts."""
-    rows: list[dict] = []
-    for j in range(k):
-        rows.append({key: float(np.asarray(val)[j])
-                     for key, val in aux.items()})
-    return rows
+    """Split a dict of (K,)-arrays into K per-row metric dicts of floats."""
+    cols = {key: np.asarray(val, np.float64)[:k].tolist()
+            for key, val in aux.items()}
+    return [{key: col[j] for key, col in cols.items()} for j in range(k)]
 
 
 def _next_pow2(k: int) -> int:
@@ -248,9 +285,11 @@ _STEP_SPAN = tracing.span("train.step")
 class VmapBackend(TrainBackend):
     """One ``jax.jit(jax.vmap(model.jax_train))`` call per flush.
 
-    Batches are padded to the next power of two (duplicating the last
-    row; padded outputs are discarded) so a fleet with varying roster
-    sizes compiles O(log K) programs instead of one per distinct K.
+    Batches are padded to the next power of two so a fleet with varying
+    roster sizes compiles O(log K) programs instead of one per distinct
+    K.  The padding rows are the staging buffer's rows after the batch
+    (a stack handed in from elsewhere is copied there first); the index
+    vectors repeat their last entry; padded outputs are discarded.
     """
 
     name = "vmap"
@@ -265,16 +304,18 @@ class VmapBackend(TrainBackend):
             fn = self._jitted[id(model)] = jax.jit(jax.vmap(model.jax_train))
         return fn
 
+    def padded_rows(self, k: int) -> int:
+        return _next_pow2(k)
+
     def train(self, model, stack, client_idx, round_idx):
-        k = stack.shape[0]
-        kp = _next_pow2(k)
+        k, n_params = stack.shape
+        kp = self.padded_rows(k)
         if kp != k:
-            pad = kp - k
-            stack = np.concatenate([stack, np.repeat(stack[-1:], pad, 0)])
-            client_idx = np.concatenate(
-                [client_idx, np.repeat(client_idx[-1:], pad)])
-            round_idx = np.concatenate(
-                [round_idx, np.repeat(round_idx[-1:], pad)])
+            if not self._is_staged(stack):
+                self.staging(k, n_params)[...] = stack
+            stack = self._stage[:kp]
+            client_idx = np.pad(client_idx, (0, kp - k), mode="edge")
+            round_idx = np.pad(round_idx, (0, kp - k), mode="edge")
         return self._step(model, stack, client_idx, round_idx, k)
 
     def _step(self, model, stack, client_idx, round_idx, k: int):
@@ -282,7 +323,11 @@ class VmapBackend(TrainBackend):
         real: the ``train.step`` span, from the inputs' copy to the device
         until the trained stack is numpy, and the rows and bytes it moved
         (``train.rows``, ``train.pad_rows``, ``device.h2d_bytes``,
-        ``device.d2h_bytes``)."""
+        ``device.d2h_bytes``).
+
+        It returns only once the results are on the host, so no transfer
+        still reads ``stack`` afterwards: that is what lets the staging
+        buffer be written again by the next flush."""
         import jax.numpy as jnp
         with _STEP_SPAN:
             new, aux = self._batched(model)(
@@ -290,6 +335,7 @@ class VmapBackend(TrainBackend):
                 jnp.asarray(client_idx, jnp.int32),
                 jnp.asarray(round_idx, jnp.int32))
             new = np.asarray(new, np.float32)
+            aux = {key: np.asarray(val) for key, val in aux.items()}
         tracing.count("train.rows", k)
         tracing.count("train.pad_rows", stack.shape[0] - k)
         tracing.count("device.h2d_bytes",
@@ -331,24 +377,15 @@ class ShardBackend(VmapBackend):
                 check_vma=False))
         return fn
 
-    def train(self, model, stack, client_idx, round_idx):
+    def padded_rows(self, k: int) -> int:
+        # A device multiple (shard_map needs an even split) of the pow2
+        # size, for jit stability.
         import jax
         d = jax.device_count()
+        kp = _next_pow2(k)
         if d <= 1:
-            return super().train(model, stack, client_idx, round_idx)
-        # Pad to a device multiple (shard_map needs an even split), then
-        # reuse the pow2 padding inside the parent for jit stability.
-        k = stack.shape[0]
-        kp = max(d, _next_pow2(k))
-        kp = -(-kp // d) * d
-        if kp != k:
-            pad = kp - k
-            stack = np.concatenate([stack, np.repeat(stack[-1:], pad, 0)])
-            client_idx = np.concatenate(
-                [client_idx, np.repeat(client_idx[-1:], pad)])
-            round_idx = np.concatenate(
-                [round_idx, np.repeat(round_idx[-1:], pad)])
-        return self._step(model, stack, client_idx, round_idx, k)
+            return kp
+        return -(-max(d, kp) // d) * d
 
 
 _TRAIN_BACKENDS: dict[str, Callable[[], TrainBackend]] = {}
@@ -404,7 +441,14 @@ class BatchTrainer:
         self.model = model
         self.backend = backend
         self.client_index = dict(client_index)
-        self._template = model.init_params()
+        leaves, self._treedef = tree_util.tree_flatten(model.init_params())
+        self._layout: list[tuple[int, int, tuple, np.dtype]] = []
+        off = 0
+        for leaf in leaves:
+            leaf = np.asarray(leaf)
+            self._layout.append((off, off + leaf.size, leaf.shape, leaf.dtype))
+            off += leaf.size
+        self._n_params = off
         self._pending: list[tuple[Any, np.ndarray, int, int]] = []
         self._results: dict[Any, tuple[Any, Any, dict]] = {}
         #: Flush sizes, newest last — benchmarks read this to report how
@@ -424,21 +468,51 @@ class BatchTrainer:
 
     @tracing.span("train.flush")
     def flush(self) -> None:
-        """Train every pending submission as one backend call."""
+        """Train every pending submission as one backend call.
+
+        Each received tree's leaves are copied once, straight into a row
+        of the backend's staging buffer; each trained tree's leaves are
+        views of one row of the step's output, which is fresh per flush
+        (read-only from a jitted step, so a consumer cannot write into a
+        neighbour's model by accident)."""
         if not self._pending:
             return
         pending, self._pending = self._pending, []
-        stack = np.stack([flatten_to_vector(tree) for _, tree, _, _ in
-                          pending]).astype(np.float32, copy=False)
-        client_idx = np.asarray([i for _, _, i, _ in pending], np.int32)
-        round_idx = np.asarray([r for _, _, _, r in pending], np.int32)
+        k = len(pending)
+        rows = self.backend.staging(k, self._n_params)
+        for row, (_, tree, _, _) in zip(rows, pending):
+            self._stage_row(row, tree)
+        client_idx = np.fromiter((i for _, _, i, _ in pending), np.int32, k)
+        round_idx = np.fromiter((r for _, _, _, r in pending), np.int32, k)
         new_stack, metrics = self.backend.train(
-            self.model, stack, client_idx, round_idx)
-        self.batch_sizes.append(len(pending))
+            self.model, rows, client_idx, round_idx)
+        if np.may_share_memory(new_stack, rows.base):
+            # A step that hands its input back: the next flush rewrites
+            # the buffer, so these rows need their own memory.
+            new_stack = new_stack.copy()
+        self.batch_sizes.append(k)
         for j, (key, tree, _, _) in enumerate(pending):
-            new_tree = unflatten_from_vector(
-                np.asarray(new_stack[j], np.float32), self._template)
-            self._results[key] = (tree, new_tree, metrics[j])
+            self._results[key] = (tree, self._unflatten(new_stack[j]),
+                                  metrics[j])
+
+    def _stage_row(self, row: np.ndarray, tree: Any) -> None:
+        """Write ``tree``'s leaves into ``row`` back to back, in
+        ``tree_leaves`` order (``flatten_to_vector``'s layout)."""
+        leaves = [np.asarray(leaf) for leaf in tree_util.tree_leaves(tree)]
+        n = sum(leaf.size for leaf in leaves)
+        if n != row.size:
+            raise ValueError(f"tree has {n} params, the model needs "
+                             f"{row.size}")
+        off = 0
+        for leaf in leaves:
+            row[off:off + leaf.size] = leaf.reshape(-1)
+            off += leaf.size
+
+    def _unflatten(self, vec: np.ndarray) -> Any:
+        """A pytree shaped like the model's template over ``vec``."""
+        return tree_util.tree_unflatten(self._treedef, [
+            vec[a:b].reshape(shape).astype(dtype, copy=False)
+            for a, b, shape, dtype in self._layout])
 
     def collect(self, key: Any) -> tuple[Any, Any, dict]:
         """(received_tree, trained_tree, metrics) for a submitted key."""

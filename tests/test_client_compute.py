@@ -21,14 +21,16 @@ import pytest
 
 jax = pytest.importorskip("jax")
 
-from repro.core import FleetConfig                         # noqa: E402
+from repro.core import FleetConfig, tracing                # noqa: E402
 from repro.core.client_compute import (BatchTrainer,       # noqa: E402
-                                       ConsensusModel, available_models,
+                                       ConsensusModel, TrainBackend,
+                                       available_models,
                                        available_train_backends, make_model,
                                        make_train_backend, register_model,
                                        register_train_backend)
 from repro.core.fleet import ConsensusObjective            # noqa: E402
-from repro.core.packetizer import flatten_to_vector        # noqa: E402
+from repro.core.packetizer import (flatten_to_vector,      # noqa: E402
+                                   unflatten_from_vector)
 from repro.data.mnist import (SyntheticMnist,              # noqa: E402
                               dirichlet_shards, load_mnist)
 
@@ -252,6 +254,167 @@ class TestBatchTrainer:
         _, tr = self._trainer()
         tr.flush()
         assert tr.batch_sizes == []
+
+
+# --------------------------------------------------------------------------
+# Staged flushes: rows written into the backend's reused buffer, trained
+# trees returned as views of the step's output
+# --------------------------------------------------------------------------
+def _staging_model(name, n):
+    kwargs = ({"n_params": 96} if name == "consensus"
+              else {"n_train": 512, "n_test": 64, "shard_size": 32,
+                    "hidden": 8})
+    return make_model(name, n, seed=3, **kwargs)
+
+
+def _submissions(model, k, salt):
+    """``k`` distinct received trees, as flatten/unflatten would give."""
+    vec0 = flatten_to_vector(model.init_params())
+    rng = np.random.default_rng([salt, k])
+    rows = (vec0 + 0.05 * rng.standard_normal((k, vec0.size))
+            ).astype(np.float32)
+    return [unflatten_from_vector(r, model.init_params()) for r in rows]
+
+
+def _stack_and_pad(backend, model, trees, ci, ri):
+    """The old path: stack flattened copies, pad by repeating the last
+    row up to the backend's padded size, train, keep the real rows."""
+    k = len(trees)
+    kp = backend.padded_rows(k)
+    stack = np.stack([flatten_to_vector(t) for t in trees])
+    pad = kp - k
+    out, met = backend.train(
+        model, np.concatenate([stack, np.repeat(stack[-1:], pad, 0)]),
+        np.concatenate([ci, np.repeat(ci[-1:], pad)]),
+        np.concatenate([ri, np.repeat(ri[-1:], pad)]))
+    return out[:k], met[:k]
+
+
+@pytest.mark.parametrize("backend,model_name,sizes,allocs", [
+    # Shrinking flushes: stale rows of a larger flush sit in the padding.
+    ("vmap", "mlp", [200, 3, 60, 1], 1),
+    ("vmap", "consensus", [200, 3, 60, 1], 1),
+    ("python", "consensus", [200, 3, 60, 1], 1),
+    # Growth past the first capacity: 4 -> 64 -> 256 rows, and 3 -> 60
+    # -> 200 unpadded.
+    ("vmap", "consensus", [3, 60, 200], 3),
+    ("python", "consensus", [3, 60, 200], 3),
+])
+def test_staged_flush_matches_stack_and_pad(backend, model_name, sizes,
+                                             allocs):
+    n = max(sizes)
+    model = _staging_model(model_name, n)
+    index = {f"c{i}": i for i in range(n)}
+    tr = BatchTrainer(model, make_train_backend(backend), index)
+    ref = make_train_backend(backend)
+    snap = tracing.snapshot()
+    for f, k in enumerate(sizes):
+        trees = _submissions(model, k, f)
+        ci = (np.arange(k, dtype=np.int32) * 7 + f) % n
+        ri = np.full(k, f, np.int32)
+        for j in range(k):
+            tr.submit((f, j), f"c{ci[j]}", trees[j], int(ri[j]))
+        tr.flush()
+        want, want_met = _stack_and_pad(ref, model, trees, ci, ri)
+        for j in range(k):
+            received, trained, met = tr.collect((f, j))
+            assert received is trees[j]
+            np.testing.assert_array_equal(flatten_to_vector(trained),
+                                          want[j])
+            assert met == want_met[j]
+    assert tr.batch_sizes == sizes
+    _, counters = tracing.delta(snap)
+    assert counters.get("train.stage_allocs", 0) == allocs
+
+
+def _flush(tr, trees, tag):
+    for j, tree in enumerate(trees):
+        tr.submit((tag, j), f"c{j}", tree, 0)
+    return [tr.collect((tag, j))[1] for j in range(len(trees))]
+
+
+def test_collected_trees_are_views_of_one_step_output(monkeypatch):
+    model = _staging_model("mlp", 8)
+    tr = BatchTrainer(model, make_train_backend("vmap"),
+                      {f"c{i}": i for i in range(8)})
+    outputs = []
+    step = tr.backend._step
+
+    def spy(*a):
+        out = step(*a)
+        outputs.append(out[0])
+        return out
+    monkeypatch.setattr(tr.backend, "_step", spy)
+    first = _flush(tr, _submissions(model, 5, 0), "a")
+    kept = [flatten_to_vector(t) for t in first]
+    second = _flush(tr, _submissions(model, 7, 1), "b")
+    assert len(outputs) == 2
+    for trees, out, other in ((first, outputs[0], outputs[1]),
+                              (second, outputs[1], outputs[0])):
+        for tree in trees:
+            for leaf in jax.tree_util.tree_leaves(tree):
+                assert np.shares_memory(leaf, out)
+                assert not np.shares_memory(leaf, other)
+                assert not np.shares_memory(leaf, tr.backend._stage)
+                assert not leaf.flags.writeable
+    # The second flush rewrote the staging buffer; the first flush's
+    # trees are untouched.
+    for tree, vec in zip(first, kept):
+        np.testing.assert_array_equal(flatten_to_vector(tree), vec)
+
+
+class _ReturnsItsInput(TrainBackend):
+    name = "identity"
+
+    def train(self, model, stack, client_idx, round_idx):
+        return stack, [{}] * stack.shape[0]
+
+
+def test_a_step_returning_its_input_gets_its_own_memory():
+    model = _staging_model("consensus", 4)
+    tr = BatchTrainer(model, _ReturnsItsInput(),
+                      {f"c{i}": i for i in range(4)})
+    trees = _submissions(model, 3, 0)
+    first = _flush(tr, trees, "a")
+    _flush(tr, _submissions(model, 3, 1), "b")
+    for tree, sent in zip(first, trees):
+        assert not np.shares_memory(tree["w"], tr.backend._stage)
+        np.testing.assert_array_equal(tree["w"], sent["w"])
+
+
+@pytest.mark.parametrize("sizes", [[5, 3], [8, 1, 2]])
+def test_staging_moves_the_same_rows_and_bytes(sizes):
+    n = max(sizes)
+    model = _staging_model("consensus", n)
+    n_params = flatten_to_vector(model.init_params()).size
+    keys = ("train.rows", "train.pad_rows", "device.h2d_bytes",
+            "device.d2h_bytes")
+
+    def moved(run):
+        snap = tracing.snapshot()
+        for f, k in enumerate(sizes):
+            run(f, k, _submissions(model, k, f))
+        _, counters = tracing.delta(snap)
+        return {key: counters.get(key, 0) for key in keys}
+
+    tr = BatchTrainer(model, make_train_backend("vmap"),
+                      {f"c{i}": i for i in range(n)})
+    ref = make_train_backend("vmap")
+
+    def staged(f, k, trees):
+        _flush(tr, trees, f)
+
+    def stacked(f, k, trees):
+        ref.train(model, np.stack([flatten_to_vector(t) for t in trees]),
+                  np.arange(k, dtype=np.int32), np.zeros(k, np.int32))
+
+    got, want = moved(staged), moved(stacked)
+    assert got == want
+    padded = [1 << (k - 1).bit_length() for k in sizes]
+    assert got["train.rows"] == sum(sizes)
+    assert got["train.pad_rows"] == sum(padded) - sum(sizes)
+    assert got["device.h2d_bytes"] == sum(4 * kp * (n_params + 2)
+                                          for kp in padded)
 
 
 # --------------------------------------------------------------------------
