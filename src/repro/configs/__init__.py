@@ -7,7 +7,8 @@ from repro.configs.base import (ModelConfig, ShapeConfig, TrainConfig, SHAPES,
 # Import side effects populate the registry.
 from repro.configs import (granite_34b, starcoder2_7b, yi_9b, gemma3_12b,
                            whisper_tiny, qwen3_moe_235b_a22b, olmoe_1b_7b,
-                           qwen2_vl_72b, xlstm_350m, hymba_1_5b)  # noqa: F401
+                           qwen2_vl_72b, xlstm_350m, hymba_1_5b,
+                           mellum2_12b_a2_5b)  # noqa: F401
 
 ARCH_IDS = [
     "granite-34b", "starcoder2-7b", "yi-9b", "gemma3-12b", "whisper-tiny",

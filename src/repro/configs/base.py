@@ -29,6 +29,13 @@ class ModelConfig:
     global_every: int = 0          # gemma3: 1 global layer per N (5 local : 1)
     full_attn_layers: tuple = ()   # hymba: explicit full-attention layer ids
     rope_theta: float = 10_000.0
+    # YaRN on the full-attention layers (0 = none; windowed layers keep the
+    # default table): Hugging Face's ``rope_type: yarn`` section
+    rope_yarn_factor: float = 0.0
+    rope_yarn_original_max: int = 0
+    rope_yarn_beta_fast: float = 32.0
+    rope_yarn_beta_slow: float = 1.0
+    rope_yarn_attention_factor: float = 1.0
     # -- MoE ----------------------------------------------------------------
     num_experts: int = 0
     num_experts_per_tok: int = 0

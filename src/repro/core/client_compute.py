@@ -61,12 +61,25 @@ class ClientModel(abc.ABC):
     * :meth:`train_fn` — ``(params_tree, round_idx, client) -> (tree,
       metrics)``, the historical per-client callable handed to
       :class:`~repro.core.server.FLClient`.
-    * :meth:`jax_train` — ``(flat_vec, client_idx, round_idx) ->
-      (flat_vec', aux)``, pure and vmappable over all three arguments
-      (``aux`` is a dict of scalar training metrics).
+    * :meth:`jax_train` — ``(flat_vec, client_idx, round_idx, frozen) ->
+      (flat_vec', aux)``, pure and vmappable over the first three
+      arguments (``aux`` is a dict of scalar training metrics).
+
+    ``frozen`` is what :meth:`frozen` returns: device arrays every client's
+    step reads and none trains (a frozen base model).  Every backend hands
+    them to its jitted step as an argument shared by the batch
+    (``in_axes=None``), never as constants, so they are not copied into the
+    compiled program and one compile serves every value of the same shape.
+    Models with nothing frozen return None.  A model may also give its own
+    batched step (:meth:`jax_train_batch`); by default it is
+    ``jax.vmap(jax_train)``.  The ``aux`` keys named in :attr:`counters`
+    are counts, added to the program's counters (``repro.core.tracing``)
+    over the real rows of every step.
     """
 
     name: str = "abstract"
+    #: ``aux`` keys that are counts for ``repro.core.tracing``.
+    counters: tuple[str, ...] = ()
 
     def __init__(self, n_clients: int, *, seed: int = 0):
         self.n_clients = int(n_clients)
@@ -89,8 +102,18 @@ class ClientModel(abc.ABC):
         """The i-th client's legacy per-client training callable."""
 
     @abc.abstractmethod
-    def jax_train(self, vec, client_idx, round_idx):
+    def jax_train(self, vec, client_idx, round_idx, frozen=None):
         """One client's local training as a pure JAX function."""
+
+    def frozen(self) -> Any:
+        """Device arrays shared by every client's step, or None."""
+        return None
+
+    def jax_train_batch(self, stack, client_idx, round_idx, frozen):
+        """The local round of a batch of clients (rows of ``stack``)."""
+        import jax
+        return jax.vmap(self.jax_train, in_axes=(0, 0, 0, None))(
+            stack, client_idx, round_idx, frozen)
 
 
 _MODELS: dict[str, Callable[..., ClientModel]] = {}
@@ -151,7 +174,7 @@ class ConsensusModel(ClientModel):
     def train_fn(self, i: int, profile: Any = None) -> Callable:
         return self.objective.train_fn(i, profile)
 
-    def jax_train(self, vec, client_idx, round_idx):
+    def jax_train(self, vec, client_idx, round_idx, frozen=None):
         import jax.numpy as jnp
         targets = jnp.asarray(self.objective.targets)
         target = targets[client_idx]
@@ -171,6 +194,14 @@ def _mlp_factory(n_clients: int, *, seed: int = 0, **kwargs) -> ClientModel:
 
 
 register_model("mlp", _mlp_factory)
+
+
+def _lm_factory(n_clients: int, *, seed: int = 0, **kwargs) -> ClientModel:
+    from repro.models.lm_client import LMClientModel
+    return LMClientModel(n_clients, seed=seed, **kwargs)
+
+
+register_model("lm", _lm_factory)
 
 
 # --------------------------------------------------------------------------
@@ -283,7 +314,9 @@ _STEP_SPAN = tracing.span("train.step")
 
 
 class VmapBackend(TrainBackend):
-    """One ``jax.jit(jax.vmap(model.jax_train))`` call per flush.
+    """One jitted ``model.jax_train_batch`` call per flush (by default
+    ``jax.vmap(model.jax_train)``), with ``model.frozen()`` passed in as an
+    argument shared by the batch.
 
     Batches are padded to the next power of two so a fleet with varying
     roster sizes compiles O(log K) programs instead of one per distinct
@@ -301,7 +334,7 @@ class VmapBackend(TrainBackend):
         fn = self._jitted.get(id(model))
         if fn is None:
             import jax
-            fn = self._jitted[id(model)] = jax.jit(jax.vmap(model.jax_train))
+            fn = self._jitted[id(model)] = jax.jit(model.jax_train_batch)
         return fn
 
     def padded_rows(self, k: int) -> int:
@@ -323,7 +356,8 @@ class VmapBackend(TrainBackend):
         real: the ``train.step`` span, from the inputs' copy to the device
         until the trained stack is numpy, and the rows and bytes it moved
         (``train.rows``, ``train.pad_rows``, ``device.h2d_bytes``,
-        ``device.d2h_bytes``).
+        ``device.d2h_bytes``), and the model's own counts over the real
+        rows (``model.counters``).
 
         It returns only once the results are on the host, so no transfer
         still reads ``stack`` afterwards: that is what lets the staging
@@ -333,7 +367,7 @@ class VmapBackend(TrainBackend):
             new, aux = self._batched(model)(
                 jnp.asarray(stack, jnp.float32),
                 jnp.asarray(client_idx, jnp.int32),
-                jnp.asarray(round_idx, jnp.int32))
+                jnp.asarray(round_idx, jnp.int32), model.frozen())
             new = np.asarray(new, np.float32)
             aux = {key: np.asarray(val) for key, val in aux.items()}
         tracing.count("train.rows", k)
@@ -342,15 +376,18 @@ class VmapBackend(TrainBackend):
                       4 * (stack.size + client_idx.size + round_idx.size))
         tracing.count("device.d2h_bytes",
                       new.nbytes + sum(v.nbytes for v in aux.values()))
+        for name in model.counters:
+            tracing.count(name, int(aux[name][:k].sum()))
         return new[:k], _aux_to_rows(aux, k)
 
 
 class ShardBackend(VmapBackend):
-    """vmap sharded over the local device mesh (``clients`` axis).
+    """The batched step sharded over the local device mesh (``clients``
+    axis).
 
     With one device (the CI case) this is exactly :class:`VmapBackend`;
     with D devices the padded batch is split D ways via ``shard_map`` so
-    each device trains K/D clients.
+    each device trains K/D clients, the frozen arrays replicated on each.
     """
 
     name = "shard"
@@ -368,11 +405,10 @@ class ShardBackend(VmapBackend):
             from jax.sharding import PartitionSpec as P
             from repro.distributed.fl_mesh import client_mesh
             mesh = client_mesh()
-            vmapped = jax.vmap(model.jax_train)
             spec = P("clients")
             fn = self._sharded[id(model)] = jax.jit(jax.shard_map(
-                vmapped, mesh=mesh,
-                in_specs=(spec, spec, spec),
+                model.jax_train_batch, mesh=mesh,
+                in_specs=(spec, spec, spec, P()),
                 out_specs=(spec, spec),
                 check_vma=False))
         return fn

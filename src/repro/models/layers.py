@@ -14,6 +14,7 @@ Conventions:
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Optional
 
 import jax
@@ -36,6 +37,52 @@ def rmsnorm(x: jax.Array, scale: jax.Array, eps: float = 1e-6) -> jax.Array:
 # --------------------------------------------------------------------------
 # RoPE / M-RoPE
 # --------------------------------------------------------------------------
+def rope_inv_freq(head_dim: int, theta: float) -> np.ndarray:
+    """Default RoPE frequencies ``theta ** (-2i / head_dim)``, float32."""
+    half = head_dim // 2
+    return (1.0 / theta ** (np.arange(half, dtype=np.float64) * 2.0
+                            / head_dim)).astype(np.float32)
+
+
+def yarn_inv_freq(head_dim: int, theta: float, factor: float,
+                  original_max_positions: int, beta_fast: float = 32.0,
+                  beta_slow: float = 1.0) -> np.ndarray:
+    """YaRN frequencies (arXiv:2309.00071) as Hugging Face's ``rope_type:
+    yarn`` defines them (``truncate`` on): slot i keeps its default
+    frequency below the ``beta_fast`` correction dimension, is divided by
+    ``factor`` above the ``beta_slow`` one, and is blended linearly in
+    between.  The attention factor scales cos and sin, not these."""
+    half = head_dim // 2
+    pos_freqs = theta ** (np.arange(half, dtype=np.float64) * 2.0
+                          / head_dim)
+    extrapolation = 1.0 / pos_freqs
+    interpolation = 1.0 / (factor * pos_freqs)
+
+    def correction_dim(rotations: float) -> float:
+        return (head_dim * math.log(original_max_positions
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(half, dtype=np.float64) - low) / (high - low),
+                   0.0, 1.0)
+    keep = 1.0 - ramp                   # share of the default frequency
+    return (interpolation * (1.0 - keep) + extrapolation * keep
+            ).astype(np.float32)
+
+
+def rope_cos_sin_freqs(positions: jax.Array, inv_freq,
+                       scale: float = 1.0) -> tuple[jax.Array, jax.Array]:
+    """positions (..., S) int32 -> cos, sin (..., S, hd/2) of the given
+    frequencies, each multiplied by ``scale`` (YaRN's attention factor)."""
+    angles = positions.astype(jnp.float32)[..., None] \
+        * jnp.asarray(inv_freq, jnp.float32)
+    return jnp.cos(angles) * scale, jnp.sin(angles) * scale
+
+
 def rope_cos_sin(positions: jax.Array, head_dim: int, theta: float,
                  sections: Optional[tuple] = None
                  ) -> tuple[jax.Array, jax.Array]:
@@ -133,24 +180,63 @@ def gqa_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                       q_pos: jax.Array, kv_pos: jax.Array,
                       causal: bool = True, window=0,
-                      chunk: int = 512) -> jax.Array:
+                      chunk: int = 512, band: int = 0) -> jax.Array:
     """Memory-efficient attention: map over query chunks so peak live memory
     is O(S * chunk) instead of O(S^2). The XLA analogue of flash attention —
-    the Pallas kernel (`repro.kernels.flash_attention`) is the TPU hot path;
-    this is the portable default for 32k+ prefill."""
+    the Pallas kernel (`repro.kernels.flash_attention`) is the TPU hot path
+    for inference; this is the portable default for 32k+ prefill, and the
+    training path (the Pallas kernel has no backward pass).
+
+    Each chunk is rematerialised in the backward pass (``jax.checkpoint``),
+    so a gradient keeps O(S * chunk) live as well.  With a static ``band``
+    > 0 (self-attention over one run of positions, ``kv_pos == q_pos``,
+    windowed to ``band``), chunk i reads only the keys from ``band`` before
+    its first query to its last: O(S * (chunk + band)) work, not O(S^2)."""
     B, S, H, hd = q.shape
     assert S % chunk == 0, (S, chunk)
     nq = S // chunk
     qc = q.reshape(B, nq, chunk, H, hd).transpose(1, 0, 2, 3, 4)
     pc = q_pos.reshape(nq, chunk)
+    if band:
+        window = band
+        pad = ((0, 0), (band, 0), (0, 0), (0, 0))
+        k, v = jnp.pad(k, pad), jnp.pad(v, pad)
+        # Padding keys sit more than ``band`` before every query: masked.
+        kv_pos = jnp.pad(kv_pos, (band, 0), constant_values=-(1 << 30))
+    starts = jnp.arange(nq, dtype=jnp.int32) * chunk
 
+    @jax.checkpoint
     def one_chunk(args):
-        qi, pi = args
-        return gqa_attention(qi, k, v, q_pos=pi, kv_pos=kv_pos,
+        qi, pi, start = args
+        ki, vi, ti = k, v, kv_pos
+        if band:
+            ki = jax.lax.dynamic_slice_in_dim(k, start, chunk + band, 1)
+            vi = jax.lax.dynamic_slice_in_dim(v, start, chunk + band, 1)
+            ti = jax.lax.dynamic_slice_in_dim(kv_pos, start, chunk + band)
+        return gqa_attention(qi, ki, vi, q_pos=pi, kv_pos=ti,
                              causal=causal, window=window)
 
-    out = jax.lax.map(one_chunk, (qc, pc))        # (nq, B, chunk, H, hd)
+    out = jax.lax.map(one_chunk, (qc, pc, starts))  # (nq, B, chunk, H, hd)
     return out.transpose(1, 0, 2, 3, 4).reshape(B, S, H, hd)
+
+
+def causal_prefix_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
+                            chunk: int, groups: int) -> jax.Array:
+    """Causal self-attention over positions 0..S-1 in ``groups`` runs of
+    queries, each reading only the keys up to its own last position (its
+    causal prefix), through :func:`chunked_attention`: the key work is
+    (groups + 1) / (2 * groups) of attending every query to all S keys."""
+    S = q.shape[1]
+    step = S // groups
+    assert S % groups == 0 and step % chunk == 0, (S, groups, chunk)
+    pos = jnp.arange(S, dtype=jnp.int32)
+    outs = []
+    for g in range(groups):
+        lo, hi = g * step, (g + 1) * step
+        outs.append(chunked_attention(q[:, lo:hi], k[:, :hi], v[:, :hi],
+                                      q_pos=pos[lo:hi], kv_pos=pos[:hi],
+                                      chunk=chunk))
+    return jnp.concatenate(outs, axis=1)
 
 
 def attention(q, k, v, *, q_pos, kv_pos, causal=True, window=0,

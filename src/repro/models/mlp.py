@@ -112,7 +112,7 @@ class MnistMLPModel(ClientModel):
 
         return _train
 
-    def jax_train(self, vec, client_idx, round_idx):
+    def jax_train(self, vec, client_idx, round_idx, frozen=None):
         params = self._unflatten_jax(vec.astype(jnp.float32))
         shard = self._shards[client_idx]              # (shard_size,) indices
         key = jax.random.fold_in(

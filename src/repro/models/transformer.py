@@ -8,9 +8,13 @@ devices and production-idiomatic). Heterogeneous attention patterns (gemma3's
 flag consumed inside the scan body as a traced window select — no parameter
 or compute duplication.
 
-MoE baseline is a scan over experts with top-k combine weights (clean GSPMD
-sharding; computes every expert — the deliberate waste shows up in the
-roofline usefulness ratio and is the target of the §Perf MoE hillclimb).
+MoE baseline (``moe_impl="scan"``) is a scan over experts with top-k
+combine weights (clean GSPMD sharding; computes every expert — E/k times
+the active work, a deliberate waste kept as the plain baseline the tests
+compare against).  ``moe_impl="ragged"`` is the dropless top-k path
+(:func:`moe_route` + :func:`moe_dispatch`: sort by expert, then
+:func:`grouped_matmul`), the one the federated ``lm`` client
+(``repro.models.lm_client``) trains through.
 """
 
 from __future__ import annotations
@@ -151,122 +155,190 @@ def _moe_block(x: jax.Array, p: dict, cfg: ModelConfig) -> jax.Array:
     return out
 
 
-MOE_CAPACITY_FACTOR = 2.0   # expert capacity = cf * TK/E (grouped MoE path)
+def moe_route(x: jax.Array, router: jax.Array, k: int
+              ) -> tuple[jax.Array, jax.Array]:
+    """Softmax router, top-``k``, renormalised (``norm_topk_prob``).
+    x (T, d) -> weights (T, k) float32 and expert ids (T, k) int32."""
+    logits = jnp.einsum("td,de->te", x, router,
+                        preferred_element_type=jnp.float32)
+    top_w, top_i = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+    return top_w / jnp.sum(top_w, axis=-1, keepdims=True), top_i
 
 
-@jax.custom_vjp
-def grouped_matmul(lhs: jax.Array, rhs: jax.Array,
-                   group_sizes: jax.Array) -> jax.Array:
-    """(T,K) x (G,K,N) -> (T,N), rows grouped by ``group_sizes``.
+def _ragged_dims(lhs_contract: int, rhs_contract: int, rhs_group):
+    return jax.lax.RaggedDotDimensionNumbers(
+        dot_dimension_numbers=(((lhs_contract,), (rhs_contract,)), ((), ())),
+        lhs_ragged_dimensions=[0],
+        rhs_group_dimensions=[] if rhs_group is None else [rhs_group])
+
+
+def _megablox(*dims: int) -> bool:
+    """Whether the grouped products run as the Pallas megablox kernels: on
+    a TPU, for dimensions on the 128 tiling."""
+    return jax.default_backend() == "tpu" and all(d % 128 == 0 for d in dims)
+
+
+def _tiles(*pairs: tuple[int, int]) -> tuple[int, ...]:
+    """The largest multiple of 128 up to ``want`` dividing each ``dim``."""
+    out = []
+    for dim, want in pairs:
+        t = min(want, dim) // 128 * 128
+        while dim % t:
+            t -= 128
+        out.append(t)
+    return tuple(out)
+
+
+def _gmm(lhs, rhs, group_sizes, transposed: bool):
+    m, k = lhs.shape
+    n = rhs.shape[1] if transposed else rhs.shape[2]
+    if _megablox(m, k, n):
+        from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+        return gmm(lhs, rhs, group_sizes, jnp.float32,
+                      _tiles((m, 512), (k, 1024), (n, 1024)),
+                      transpose_rhs=transposed)
+    return jax.lax.ragged_dot_general(
+        lhs, rhs, group_sizes, _ragged_dims(1, 2 if transposed else 1, 0),
+        preferred_element_type=jnp.float32)
+
+
+def _tgmm(lhs, dout, group_sizes):
+    """Per group g: ``lhs_g^T @ dout_g`` -> (G, K, N) float32."""
+    (m, k), n = lhs.shape, dout.shape[1]
+    if _megablox(m, k, n):
+        from jax.experimental.pallas.ops.tpu.megablox.gmm import tgmm
+        return tgmm(lhs.T, dout, group_sizes, jnp.float32,
+                       _tiles((m, 512), (k, 1024), (n, 1024)),
+                       num_actual_groups=group_sizes.shape[0])
+    return jax.lax.ragged_dot_general(
+        lhs, dout, group_sizes, _ragged_dims(0, 0, None),
+        preferred_element_type=jnp.float32)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
+                   transposed: bool = False) -> jax.Array:
+    """(T,K) x (G,K,N) -> (T,N) float32, rows grouped by ``group_sizes``
+    (``sum(group_sizes) == T``).  With ``transposed`` the weights are
+    (G,N,K).  ``lhs`` and ``rhs`` share a dtype; the products accumulate
+    in float32.  On a TPU, at dimensions on the 128 tiling, the products
+    are JAX's Pallas megablox kernels (``gmm``, ``tgmm``); elsewhere
+    ``ragged_dot``, which the TPU compiler runs at a fraction of the
+    kernels' rate.
 
     jax's built-in VJP for ragged_dot falls back to dense per-group masks
     ((T,T) and (G,T,K) f32 monsters — observed 4 GiB buffers in the qwen3
-    cell). Both transposes are themselves ragged products, so this custom
-    VJP keeps the backward ragged:
-      dlhs = ragged_dot(dout, rhs^T)            (ragged non-contracting)
-      drhs = ragged_dot_general(lhs, dout)      (ragged CONTRACTING -> per
+    cell). Both transposes are themselves grouped products, so this custom
+    VJP keeps the backward grouped:
+      dlhs = gmm(dout, rhs^T)                   (grouped rows)
+      drhs = tgmm(lhs, dout)                    (grouped CONTRACTING -> per
                                                  group lhs_g^T @ dout_g)
+    When ``rhs`` is not differentiated (frozen expert weights), ``lhs`` is
+    not kept for the backward pass and ``drhs`` is never computed.
     """
-    return jax.lax.ragged_dot(lhs, rhs, group_sizes)
+    return _gmm(lhs, rhs, group_sizes, transposed)
 
 
-def _gmm_fwd(lhs, rhs, group_sizes):
-    return jax.lax.ragged_dot(lhs, rhs, group_sizes), (lhs, rhs, group_sizes)
+def _gmm_fwd(lhs, rhs, group_sizes, transposed):
+    out = _gmm(lhs.value, rhs.value, group_sizes.value, transposed)
+    kept = lhs.value if rhs.perturbed else None
+    return out, (kept, rhs.value, group_sizes.value)
 
 
-def _gmm_bwd(res, dout):
+def _gmm_bwd(transposed, res, dout):
     lhs, rhs, gs = res
-    dlhs = jax.lax.ragged_dot(dout, jnp.swapaxes(rhs, 1, 2), gs)
-    dn = jax.lax.RaggedDotDimensionNumbers(
-        dot_dimension_numbers=(((0,), (0,)), ((), ())),
-        lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
-    drhs = jax.lax.ragged_dot_general(lhs, dout.astype(lhs.dtype), gs, dn)
-    dgs = np.zeros(gs.shape, dtype=jax.dtypes.float0)
-    return dlhs.astype(lhs.dtype), drhs.astype(rhs.dtype), dgs
+    dout = dout.astype(rhs.dtype)
+    dlhs = _gmm(dout, rhs, gs, not transposed)
+    drhs = None
+    if lhs is not None:
+        drhs = (_tgmm(dout, lhs, gs) if transposed
+                else _tgmm(lhs, dout, gs)).astype(rhs.dtype)
+    return dlhs.astype(rhs.dtype), drhs, None
 
 
-grouped_matmul.defvjp(_gmm_fwd, _gmm_bwd)
+grouped_matmul.defvjp(_gmm_fwd, _gmm_bwd, symbolic_zeros=True)
+
+
+def moe_dispatch(x: jax.Array, top_w: jax.Array, top_i: jax.Array,
+                 wg: jax.Array, wu: jax.Array, wd: jax.Array,
+                 reduce=None, transposed: bool = False) -> jax.Array:
+    """Dropless top-k SwiGLU experts: x (T, d) -> (T, d).
+
+    The T*k token-expert rows are sorted by expert and each expert's run
+    goes through :func:`grouped_matmul`: every row is computed, none is
+    dropped, and only top-k work is done.  The weights are (E, d, F),
+    (E, d, F), (E, F, d), or each (out, in) with ``transposed``.
+    ``reduce`` sums partial products over a mesh axis (the sharded shelf
+    path); None off-mesh."""
+    E, K = wg.shape[0], top_i.shape[-1]
+    T = x.shape[0]
+    flat_e = top_i.reshape(-1)                                   # (T*K,)
+    # Each row's place in expert order: its expert's start plus the rows
+    # of that expert before it (a cumulative count, not a sort).
+    onehot = (flat_e[:, None] == jnp.arange(E, dtype=flat_e.dtype)
+              ).astype(jnp.int32)                                # (TK, E)
+    group_sizes = jnp.sum(onehot, axis=0)
+    before = jnp.take_along_axis(jnp.cumsum(onehot, axis=0) - onehot,
+                                 flat_e[:, None], axis=1)[:, 0]
+    place = jnp.cumsum(group_sizes)[flat_e] - group_sizes[flat_e] + before
+    order = jnp.zeros_like(flat_e).at[place].set(
+        jnp.arange(T * K, dtype=flat_e.dtype))                   # place->row
+    x_sorted = jnp.take(x, order // K, axis=0)                   # (TK, d)
+    g = grouped_matmul(x_sorted, wg, group_sizes, transposed)
+    u = grouped_matmul(x_sorted, wu, group_sizes, transposed)
+    if reduce is not None:
+        g, u = reduce(g, "data"), reduce(u, "data")
+    h = (jax.nn.silu(g) * u).astype(x.dtype)                     # (TK, F)
+    o = grouped_matmul(h, wd, group_sizes, transposed)           # (TK, d)
+    if reduce is not None:
+        o = reduce(o, "model")
+    o_rows = jnp.take(o.astype(x.dtype), place, axis=0)
+    return jnp.einsum("tkd,tk->td", o_rows.reshape(T, K, -1),
+                      top_w.astype(x.dtype),
+                      preferred_element_type=jnp.float32).astype(x.dtype)
 
 
 def _moe_block_ragged(x: jax.Array, p: dict, cfg: ModelConfig) -> jax.Array:
-    """Dropless top-k MoE via sort + ragged_dot (the §Perf rewrite).
+    """Dropless top-k MoE: :func:`moe_route`, then :func:`moe_dispatch`
+    (sort by expert + :func:`grouped_matmul`; no capacity, no row dropped,
+    top-k FLOPs only).
 
-    Token-parallel: every device keeps its own tokens, contracts against its
-    (d/dp, F/tp) weight shards, and the partial sums meet in two small psums
-    + one d-axis all-gather — no per-expert weight/activation collectives
-    and FLOPs are top-k-only (vs. the scan baseline's all-expert compute).
-    Off-mesh it runs the same math single-device (used by the equivalence
+    Token-parallel under a mesh: every device keeps its own tokens,
+    contracts against its (d/dp, F/tp) weight shards, and the partial sums
+    meet in psums over ``data`` and ``model`` + one d-axis all-gather — no
+    per-expert weight/activation collectives.  Off-mesh it runs the same
+    math on one device (the federated ``lm`` client and the equivalence
     tests)."""
-    from repro.distributed.sharding import active_mesh, constraint
-    E, K = cfg.num_experts, cfg.num_experts_per_tok
+    from repro.distributed.sharding import active_mesh
+    K = cfg.num_experts_per_tok
     B, S, d = x.shape
     mesh = active_mesh()
 
     def local_moe(x_l, router_l, wg_l, wu_l, wd_l):
         data_ax = mesh is not None and "data" in mesh.axis_names
+        model_ax = mesh is not None and "model" in mesh.axis_names
         Bl, Sl, _ = x_l.shape
-        T = Bl * Sl
-        xf = x_l.reshape(T, d)
+        xf = x_l.reshape(Bl * Sl, d)
         if data_ax:
             dp = jax.lax.axis_size("data")
             d_loc = d // dp
             di = jax.lax.axis_index("data")
-            x_slice = jax.lax.dynamic_slice_in_dim(xf, di * d_loc, d_loc, 1)
-        else:
-            x_slice = xf
-        logits = jnp.einsum("td,de->te", x_slice, router_l,
+            xf = jax.lax.dynamic_slice_in_dim(xf, di * d_loc, d_loc, 1)
+        logits = jnp.einsum("td,de->te", xf, router_l,
                             preferred_element_type=jnp.float32)
         if data_ax:
             logits = jax.lax.psum(logits, "data")
-        probs = jax.nn.softmax(logits, axis=-1)
-        top_w, top_i = jax.lax.top_k(probs, K)                  # (T, K)
+        top_w, top_i = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), K)
         top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)
 
-        flat_e = top_i.reshape(-1)                               # (T*K,)
-        TK = T * K
-        order = jnp.argsort(flat_e)
-        tok_of_row = order // K
-        x_sorted = jnp.take(x_slice, tok_of_row, axis=0)         # (TK, d_l)
-        group_sizes = jnp.zeros((E,), jnp.int32).at[flat_e].add(1)
-
-        # Capacity-grouped dispatch: contiguous (sorted) expert segments are
-        # gathered into a dense (E, CAP, d) tensor so the expert FFN is a
-        # single batched matmul (clean VJP + partitioning on every backend;
-        # ragged_dot lowers to dense one-hot expansions off-TPU). Rows past
-        # an expert's capacity are dropped (GShard semantics, cf = 2).
-        cap = min(TK, int(-(-TK // E) * MOE_CAPACITY_FACTOR))
-        starts = jnp.cumsum(group_sizes) - group_sizes
-        slot = jnp.arange(cap, dtype=jnp.int32)
-        valid = slot[None, :] < group_sizes[:, None]             # (E, CAP)
-        rows = jnp.where(valid, starts[:, None] + slot[None, :], TK)
-        x_pad = jnp.concatenate(
-            [x_sorted, jnp.zeros((1, x_sorted.shape[1]), x_sorted.dtype)])
-        x_grp = jnp.take(x_pad, rows, axis=0)                    # (E,CAP,d_l)
-
-        g = jnp.einsum("ecd,edf->ecf", x_grp, wg_l,
-                       preferred_element_type=jnp.float32)
-        u = jnp.einsum("ecd,edf->ecf", x_grp, wu_l,
-                       preferred_element_type=jnp.float32)
-        if data_ax:
-            g = jax.lax.psum(g, "data")
-            u = jax.lax.psum(u, "data")
-        h = (jax.nn.silu(g) * u).astype(x_l.dtype)               # (E,CAP,F_l)
-        o = jnp.einsum("ecf,efd->ecd", h, wd_l,
-                       preferred_element_type=jnp.float32)
-        if mesh is not None and "model" in mesh.axis_names:
-            o = jax.lax.psum(o, "model")                         # (E,CAP,d_l)
-        # scatter rows back to sorted order (dropped rows contribute zero)
-        o_sorted = jnp.zeros((TK + 1, o.shape[-1]), o.dtype).at[
-            rows.reshape(-1)].add(o.reshape(-1, o.shape[-1])
-                                  * valid.reshape(-1, 1))
-        o_unsorted = jnp.take(
-            o_sorted[:TK], jnp.argsort(order), axis=0)
-        o_tok = jnp.einsum("tkd,tk->td",
-                           o_unsorted.reshape(T, K, -1),
-                           top_w.astype(o.dtype))
+        def reduce(t, axis):
+            on = data_ax if axis == "data" else model_ax
+            return jax.lax.psum(t, axis) if on else t
+        o_tok = moe_dispatch(xf, top_w, top_i, wg_l, wu_l, wd_l,
+                             reduce=reduce if mesh is not None else None)
         if data_ax:
             o_tok = jax.lax.all_gather(o_tok, "data", axis=1, tiled=True)
-        return o_tok.reshape(Bl, Sl, d).astype(x_l.dtype)
+        return o_tok.reshape(Bl, Sl, d)
 
     if mesh is None:
         return local_moe(x, p["router"], p["we_gate"], p["we_up"],

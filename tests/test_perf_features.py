@@ -16,6 +16,9 @@ from repro.models.xlstm import mlstm_chunked, mlstm_parallel
 
 
 class TestCapacityGroupedMoe:
+    """The dropless ragged MoE (sort + grouped_matmul) against the scan over
+    every expert."""
+
     def _setup(self):
         cfg = smoke_variant(get_config("qwen3-moe-235b-a22b"))
         rng = jax.random.PRNGKey(0)
@@ -23,8 +26,7 @@ class TestCapacityGroupedMoe:
         tokens = jax.random.randint(rng, (2, 16), 0, cfg.vocab_size)
         return cfg, params, {"tokens": tokens, "labels": tokens}
 
-    def test_loss_matches_scan_baseline_without_drops(self, monkeypatch):
-        monkeypatch.setattr(T, "MOE_CAPACITY_FACTOR", 1000.0)
+    def test_loss_matches_scan_baseline_without_drops(self):
         cfg, params, batch = self._setup()
         l_scan = float(T.decoder_loss(cfg, params, batch, moe_impl="scan",
                                       remat_policy="none"))
@@ -32,8 +34,7 @@ class TestCapacityGroupedMoe:
                                      remat_policy="none"))
         np.testing.assert_allclose(l_scan, l_grp, rtol=1e-5)
 
-    def test_grads_match_scan_baseline(self, monkeypatch):
-        monkeypatch.setattr(T, "MOE_CAPACITY_FACTOR", 1000.0)
+    def test_grads_match_scan_baseline(self):
         cfg, params, batch = self._setup()
         g1 = jax.grad(lambda p: T.decoder_loss(
             cfg, p, batch, moe_impl="scan", remat_policy="none"))(params)
@@ -44,17 +45,64 @@ class TestCapacityGroupedMoe:
                 np.asarray(g1["layers"][k]), np.asarray(g2["layers"][k]),
                 rtol=1e-4, atol=1e-6)
 
-    def test_capacity_drops_are_bounded(self, monkeypatch):
-        """At cf=2 with a random router, dropped mass is small: outputs stay
-        close to the dropless result."""
+    def test_one_expert_pair_takes_every_token_and_drops_none(self):
+        """A zero router ties every expert, so top-k sends every token to
+        the same k experts (the lowest ids): a capacity-grouped dispatch
+        would drop all but a share of their rows.  The dropless path still
+        matches the all-expert scan, forward and gradients."""
         cfg, params, batch = self._setup()
-        monkeypatch.setattr(T, "MOE_CAPACITY_FACTOR", 1000.0)
-        full = float(T.decoder_loss(cfg, params, batch, moe_impl="ragged",
-                                    remat_policy="none"))
-        monkeypatch.setattr(T, "MOE_CAPACITY_FACTOR", 2.0)
-        capped = float(T.decoder_loss(cfg, params, batch, moe_impl="ragged",
-                                      remat_policy="none"))
-        assert abs(full - capped) < 0.05
+        params["layers"]["router"] = jnp.zeros_like(params["layers"]["router"])
+        x = jax.random.normal(jax.random.PRNGKey(1), (16, cfg.d_model))
+        top_w, top_i = T.moe_route(x, params["layers"]["router"][0],
+                                   cfg.num_experts_per_tok)
+        assert set(np.unique(np.asarray(top_i))) == set(
+            range(cfg.num_experts_per_tok))
+
+        def loss(p, impl):
+            return T.decoder_loss(cfg, p, batch, moe_impl=impl,
+                                  remat_policy="none")
+        np.testing.assert_allclose(float(loss(params, "scan")),
+                                   float(loss(params, "ragged")), rtol=1e-5)
+        g1 = jax.grad(loss)(params, "scan")
+        g2 = jax.grad(loss)(params, "ragged")
+        for k in ("we_gate", "we_up", "we_down", "wq"):
+            np.testing.assert_allclose(
+                np.asarray(g1["layers"][k]), np.asarray(g2["layers"][k]),
+                rtol=1e-4, atol=1e-6)
+
+
+class TestMegabloxGroupedMatmul:
+    """``grouped_matmul``'s TPU path (the Pallas megablox kernels, run here
+    in the interpreter) against its ``ragged_dot`` path: the same products
+    and gradients, up to the bfloat16 rounding of the gradients (0.5%)."""
+
+    @pytest.mark.parametrize("transposed", [False, True])
+    def test_matches_ragged_dot(self, monkeypatch, transposed):
+        import functools
+        import importlib
+        from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
+        mb = importlib.import_module(
+            "jax.experimental.pallas.ops.tpu.megablox.gmm")
+        rng = np.random.default_rng(0)
+        m, k, n, g = 512, 256, 384, 8
+        sizes = jnp.asarray(np.bincount(rng.integers(0, g, m), minlength=g),
+                            jnp.int32)
+        x = jnp.asarray(rng.standard_normal((m, k)), jnp.bfloat16)
+        w = jnp.asarray(rng.standard_normal(
+            (g, n, k) if transposed else (g, k, n)), jnp.bfloat16)
+
+        def loss(x, w):
+            return (T.grouped_matmul(x, w, sizes, transposed) ** 2).sum()
+        want = jax.value_and_grad(loss, argnums=(0, 1))(x, w)
+        monkeypatch.setattr(mb, "gmm", functools.partial(gmm, interpret=True))
+        monkeypatch.setattr(mb, "tgmm",
+                            functools.partial(tgmm, interpret=True))
+        monkeypatch.setattr(T, "_megablox", lambda *dims: True)
+        got = jax.value_and_grad(loss, argnums=(0, 1))(x, w)
+        assert float(got[0]) == float(want[0])
+        for a, b in zip(got[1], want[1]):
+            a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+            assert np.abs(a - b).max() <= 5e-3 * np.abs(b).max()
 
 
 class TestChunkedMlstm:

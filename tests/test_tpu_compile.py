@@ -109,3 +109,22 @@ def test_topk_scatter_compiles(sds):
 def test_checksum_compiles(sds):
     _assert_native(checksum_pallas, sds((1 << 20,), jnp.int32))
 
+
+
+def test_expert_grouped_products_compile(sds, monkeypatch):
+    """The ``lm`` model's expert products at the Mellum2 widths (one silo's
+    4,096 tokens x top-8 rows, 64 experts of 2304 -> 896, weights stored
+    (out, in)): forward and the input gradient through the frozen weights
+    lower to the megablox kernels."""
+    from repro.models import transformer as T
+    monkeypatch.setattr(T, "_megablox",
+                        lambda *dims: all(d % 128 == 0 for d in dims))
+    rows, d, f, e = 4096 * 8, 2304, 896, 64
+
+    def dx(x, w, sizes):
+        return jax.grad(lambda x: T.grouped_matmul(x, w, sizes, True)
+                        .sum())(x)
+    compiled = jax.jit(dx).lower(
+        sds((rows, d), jnp.bfloat16), sds((e, f, d), jnp.bfloat16),
+        sds((e,), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
