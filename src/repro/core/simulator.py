@@ -241,9 +241,14 @@ class Simulator:
         self.now_ns: int = 0
         self._queue: list[_Event] = []
         # Batched engine: flights live in their own tuple heap (C-speed
-        # comparisons) plus a registry for the deep-ingestion pass.
+        # comparisons).  For the deep-ingestion pass, each ``(addr, txn)``
+        # key (None: heterogeneous flights) keeps a heap of its flights'
+        # pending statically unsafe packets, ``(arrival, tie, flight)``,
+        # whose top is the key's bound.  A sync round shares one txn pair
+        # across every client, so the server's downlink flights all share
+        # one key.
         self._flightq: list[tuple[int, int, _Flight]] = []
-        self._active_flights: list[_Flight] = []
+        self._unsafe: dict = {}
         self._tie_n = 0
         self._nodes: dict[str, Node] = {}
         self._links: dict[tuple[str, str], Link] = {}
@@ -437,13 +442,34 @@ class Simulator:
                          arrivals[order].tolist(),
                          [tie0 + i for i in olist],
                          csum, safe_until, key, dst)
-        self._active_flights.append(flight)
+        if safe_until < k:
+            heapq.heappush(self._unsafe.setdefault(key, []),
+                           (flight.arrivals[safe_until],
+                            flight.ties[safe_until], flight))
         heapq.heappush(self._flightq,
                        (flight.arrivals[0], flight.ties[0], flight))
 
+    def _rebound(self, fl: _Flight) -> None:
+        """``fl.safe_until`` advanced past a delivered unsafe packet: seat
+        its next unsafe packet, if any, in its key's heap and drop the
+        entries that no longer name a flight's pending unsafe packet from
+        the top, so the top is the key's bound again."""
+        key = fl.key
+        hq = self._unsafe[key]
+        su = fl.safe_until
+        if su < len(fl.packets):
+            heapq.heappush(hq, (fl.arrivals[su], fl.ties[su], fl))
+        while hq:
+            _, tie, f = hq[0]
+            s = f.safe_until
+            if s < len(f.packets) and f.ties[s] == tie:
+                return
+            heapq.heappop(hq)
+        del self._unsafe[key]
+
     # -- the deep-ingestion pass (batched engine) ----------------------------
     @tracing.span("engine.flight_pass")
-    def _flight_pass(self, until_ns: Optional[int]) -> int:
+    def _flight_pass(self, until_ns: Optional[int], first: _Flight) -> int:
         """Bulk-ingest every eligible pending flight packet below the next
         *effectful* point of the calendar; returns packets ingested.
 
@@ -463,7 +489,27 @@ class Simulator:
         * per transaction: the first *statically* unsafe packet (non-DATA /
           the transaction's last packet) of any flight carrying the same
           ``(sender, txn)`` key, whose processing delivers/ACKs/NACKs and
-          reads the state this transaction's ingestion writes.
+          reads the state this transaction's ingestion writes.  A
+          heterogeneous (key None) flight's first unsafe packet bounds
+          every flight, like a calendar event.
+
+        The per-key bounds are kept up to date rather than rebuilt: a key's
+        bound changes only when one of its flights is planned
+        (:meth:`transmit_burst`) or its ``safe_until`` advances past a
+        delivered unsafe packet (:meth:`_rebound`); a flight finishes only
+        after its last unsafe packet, so finishing changes no bound.  Each
+        update costs a heap push or pop in the key's heap.
+
+        The pass visits only flights whose due packet lies before the
+        global bound, popped in order from the flight heap, starting with
+        ``first`` (the flight :meth:`run` has just popped).  Each is
+        ingested up to its key's bound, then re-seated once the walk ends,
+        so no flight is popped twice in one pass; ``first`` is re-seated
+        only if it ingested, since otherwise :meth:`run` delivers its due
+        packet.  Flights due at or after the global bound have nothing to
+        ingest, so the pass costs the flights that are due, not every
+        flight in flight.  Each visited flight counts into
+        ``engine.flight_visits``.
 
         Because ingestion never crosses those points, every timer handler
         still observes exactly the counters and receiver state it would
@@ -478,7 +524,6 @@ class Simulator:
         other transactions (no shipped transport or FL callback reads them
         mid-run; final stats are exact either way).
         """
-        act = self._active_flights
         queue = self._queue
         inf = 1 << 62
         gt, gtie = inf, inf
@@ -487,86 +532,80 @@ class Simulator:
             gt, gtie = h.time_ns, h.tie
         if until_ns is not None and until_ns < gt:
             gt, gtie = until_ns, inf
-        # Per-key bounds: earliest statically unsafe packet per (addr, txn);
-        # a heterogeneous (key=None) flight bounds everyone.
-        key_bound: dict = {}
-        compact = False
-        for f in act:
-            i = f.idx
-            nf = len(f.packets)
-            if i >= nf:
-                compact = True
-                continue
-            su = f.safe_until
-            if su >= nf:
-                continue
-            t2, k2 = f.arrivals[su], f.ties[su]
-            if f.key is None:
-                if t2 < gt or (t2 == gt and k2 < gtie):
-                    gt, gtie = t2, k2
-            else:
-                cur = key_bound.get(f.key)
-                if cur is None or t2 < cur[0] or (t2 == cur[0]
-                                                  and k2 < cur[1]):
-                    key_bound[f.key] = (t2, k2)
+        unsafe = self._unsafe
+        hb = unsafe.get(None)
+        if hb is not None:
+            t2, k2, _ = hb[0]
+            if t2 < gt or (t2 == gt and k2 < gtie):
+                gt, gtie = t2, k2
 
-        total = 0
+        total = visits = 0
         stats = self.stats
         flightq = self._flightq
         horizon = self._flight_horizon_ns
-        for f in act:
+        seats = []                  # re-seated once the walk ends
+        f = first
+        while f is not None:
+            visits += 1
             i = f.idx
-            nf = len(f.packets)
-            if i >= nf or f.bulk_dead or f.refused_idx == i:
-                continue
-            bulk = f.dst._bulk0
-            if bulk is None:
-                continue
-            bt, btie = gt, gtie
-            kb = key_bound.get(f.key) if f.key is not None else None
-            if kb is not None and (kb[0] < bt or (kb[0] == bt
-                                                  and kb[1] < btie)):
-                bt, btie = kb
             arr = f.arrivals
             ties = f.ties
-            jmax = min(f.safe_until, nf)
-            j = bisect_left(arr, bt, i, jmax)
-            while j < jmax and arr[j] == bt and ties[j] < btie:
-                j += 1
-            if j <= i:
-                continue
-            self.now_ns = arr[i]
-            c = bulk(f.packets, i, j, arr)
-            if c <= 0:
-                if c < 0:
-                    f.bulk_dead = True
-                else:
-                    f.refused_idx = i
-                continue
-            csum = f.bytes_csum
-            stats["packets_delivered"] += c
-            stats["delivered_data"] += c
-            stats["bytes_delivered"] += csum[i + c] - csum[i]
-            total += c
-            i += c
-            f.idx = i
-            if arr[i - 1] > horizon:
-                horizon = arr[i - 1]
-            if i < nf:
-                if i < j:
-                    # Dynamic stop before the bound: skip the wasted pass
-                    # when this packet pops (the hook already declined it).
-                    f.refused_idx = i
-                tie2 = ties[i]
-                f.seated_tie = tie2
-                heapq.heappush(flightq, (arr[i], tie2, f))
+            bulk = f.dst._bulk0
+            c = 0
+            if not f.bulk_dead and f.refused_idx != i and bulk is not None:
+                bt, btie = gt, gtie
+                kb = unsafe.get(f.key)
+                if kb is not None:
+                    t2, k2, _ = kb[0]
+                    if t2 < bt or (t2 == bt and k2 < btie):
+                        bt, btie = t2, k2
+                jmax = f.safe_until
+                j = bisect_left(arr, bt, i, jmax)
+                while j < jmax and arr[j] == bt and ties[j] < btie:
+                    j += 1
+                if j > i:
+                    self.now_ns = arr[i]
+                    c = bulk(f.packets, i, j, arr)
+                    if c < 0:
+                        f.bulk_dead = True
+                    elif c == 0:
+                        f.refused_idx = i
+                    else:
+                        csum = f.bytes_csum
+                        stats["packets_delivered"] += c
+                        stats["delivered_data"] += c
+                        stats["bytes_delivered"] += csum[i + c] - csum[i]
+                        total += c
+                        i += c
+                        f.idx = i
+                        if arr[i - 1] > horizon:
+                            horizon = arr[i - 1]
+                        if i < j:
+                            # Dynamic stop before the bound: skip the wasted
+                            # pass when this packet pops (the hook already
+                            # declined it).
+                            f.refused_idx = i
+            if i < len(arr):
+                if c > 0 or f is not first:
+                    tie2 = ties[i]
+                    f.seated_tie = tie2
+                    seats.append((arr[i], tie2, f))
             else:
                 f.seated_tie = -1
-                compact = True
-        if compact:
-            self._active_flights = [f for f in act
-                                    if f.idx < len(f.packets)]
+            # Next candidate: the heap's first live seat before the bound.
+            f = None
+            while flightq:
+                t, tie, g = flightq[0]
+                if t > gt or (t == gt and tie >= gtie):
+                    break
+                heapq.heappop(flightq)
+                if tie == g.seated_tie:
+                    f = g
+                    break
+        for s in seats:
+            heapq.heappush(flightq, s)
         self._flight_horizon_ns = horizon
+        tracing.count("engine.flight_visits", visits)
         return total
 
     # -- main loop -----------------------------------------------------------
@@ -624,7 +663,7 @@ class Simulator:
                 i = fl.idx
                 if (not fl.bulk_dead and fl.refused_idx != i
                         and i < fl.safe_until and fl.dst._bulk0 is not None):
-                    c = self._flight_pass(until_ns)
+                    c = self._flight_pass(until_ns, fl)
                     n += c
                     n_bulk += c
                     if n >= max_events:
@@ -657,6 +696,7 @@ class Simulator:
                             break
                         su += 1
                     fl.safe_until = su
+                    self._rebound(fl)
                 if n >= max_events:
                     raise _budget_error()
                 if i < nf:
@@ -664,11 +704,9 @@ class Simulator:
                     fl.seated_tie = tie2
                     heapq.heappush(flightq, (fl.arrivals[i], tie2, fl))
                 else:
+                    # Finished: its last unsafe packet came first, so the
+                    # flight already left its key's bound (_rebound).
                     fl.seated_tie = -1
-                    try:
-                        self._active_flights.remove(fl)
-                    except ValueError:
-                        pass
             else:
                 # Drained: the last processed thing may have been a
                 # bulk-ingested arrival.

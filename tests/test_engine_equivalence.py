@@ -20,8 +20,9 @@ from repro.core import (BernoulliLoss, ConsensusObjective, DropList, FLConfig,
                         keyed_uniforms, make_transport, packet_key_arrays,
                         packetize)
 from repro.core.channel import JITTER_STREAM, LOSS_STREAM
-from repro.core.fleet import links_for, sample_profiles
-from repro.core.packets import HEADER_BYTES, make_data_packet
+from repro.core.fleet import DEFAULT_MIX, links_for, sample_profiles
+from repro.core.packets import (HEADER_BYTES, PacketKind, make_data_packet,
+                                make_nack)
 
 NS = 1_000_000_000
 SERVER = "10.0.0.1"
@@ -154,29 +155,79 @@ class TestDirectTransferEquivalence:
 # --------------------------------------------------------------------------
 # Fleet rounds: full FL stack, heterogeneous cohorts
 # --------------------------------------------------------------------------
-def _fleet_round_digest(engine, kind, seed, *, n_clients=8, rounds=2,
-                        n_params=600):
-    fleet = FleetConfig(n_clients=n_clients, seed=seed,
-                        participation_fraction=0.75,
-                        round_deadline_ns=90 * NS, engine=engine)
+def _build_fleet(engine, kind, seed, *, n_clients=8, n_params=600,
+                 mode="sync", participation=0.75, deadline_ns=90 * NS,
+                 cohort_mix=DEFAULT_MIX, timeout_ns=4 * NS,
+                 udp_deadline_ns=6 * NS):
+    fleet = FleetConfig(n_clients=n_clients, seed=seed, mode=mode,
+                        participation_fraction=participation,
+                        round_deadline_ns=deadline_ns, engine=engine,
+                        cohort_mix=cohort_mix)
     objective = ConsensusObjective(n_clients, n_params, seed=seed)
     cfg = FLConfig(aggregation="fedavg",
-                   transport=TransportConfig(kind=kind, timeout_ns=4 * NS,
-                                             udp_deadline_ns=6 * NS))
+                   transport=TransportConfig(kind=kind, timeout_ns=timeout_ns,
+                                             udp_deadline_ns=udp_deadline_ns))
     sim, system, _ = build_fleet(fleet, objective.init_params(),
                                  objective.train_fn, cfg)
-    results = [system.run_round() for _ in range(rounds)]
+    return sim, system
+
+
+def _fleet_round_digest(engine, kind, seed, *, rounds=2, **shape):
+    sim, system = _build_fleet(engine, kind, seed, **shape)
+    if shape.get("mode", "sync") == "sync":
+        results = [system.run_round() for _ in range(rounds)]
+    else:
+        results = system.run_rounds(rounds)
     blob = repr((sim.now_ns, sorted(sim.stats.items()),
                  [dataclasses.asdict(r) for r in results],
                  system.global_params["w"].tobytes()))
     return hashlib.sha256(blob.encode()).hexdigest()
 
 
+# Many flights at once: 64 clients, mostly on lossy cohorts, so tens of
+# uplink flights overlap with retransmission flights of the same
+# transactions, sender timeouts and the round deadline.
+_LOSSY_MIX = (("fiber", 0.2), ("lte", 0.4), ("congested-edge", 0.4))
+_MANY_FLIGHTS = dict(n_clients=64, n_params=6000, participation=1.0,
+                     cohort_mix=_LOSSY_MIX, timeout_ns=2 * NS,
+                     udp_deadline_ns=3 * NS)
+
+
 @pytest.mark.parametrize("kind", available_transports())
-@pytest.mark.parametrize("seed", [0, 1, 7])
-def test_fleet_round_bit_identical(kind, seed):
-    assert _fleet_round_digest("per_packet", kind, seed) == \
-        _fleet_round_digest("batched", kind, seed)
+@pytest.mark.parametrize("seed,shape", [
+    pytest.param(0, {}, id="0"),
+    pytest.param(1, {}, id="1"),
+    pytest.param(7, {}, id="7"),
+    # One sync round cut by a deadline, and a short FedBuff run.
+    pytest.param(11, dict(_MANY_FLIGHTS, rounds=1, deadline_ns=6 * NS),
+                 id="fleet64-sync"),
+    pytest.param(11, dict(_MANY_FLIGHTS, rounds=3, mode="async",
+                          deadline_ns=None), id="fleet64-async"),
+])
+def test_fleet_round_bit_identical(kind, seed, shape):
+    assert _fleet_round_digest("per_packet", kind, seed, **shape) == \
+        _fleet_round_digest("batched", kind, seed, **shape)
+
+
+def test_flight_visits_counted_per_round():
+    # The pass walks only the flights due before its bound: far fewer
+    # than the flights in flight when it runs.
+    sim, system = _build_fleet("batched", "mudp", 11, deadline_ns=6 * NS,
+                               **_MANY_FLIGHTS)
+    live = []
+    walk = sim._flight_pass
+
+    def counting_pass(until_ns, first):
+        live.append(1 + sum(1 for _, tie, f in sim._flightq
+                            if tie == f.seated_tie))
+        return walk(until_ns, first)
+
+    sim._flight_pass = counting_pass
+    result = system.run_round()
+    visits = result.counters["engine.flight_visits"]
+    passes = result.spans["engine.flight_pass"][0]
+    assert passes == len(live) > 0
+    assert passes <= visits < passes * (sum(live) / len(live)) / 2
 
 
 # --------------------------------------------------------------------------
@@ -244,26 +295,168 @@ class TestEnginePlumbing:
         assert a.stats_digest() == b.stats_digest()
 
     def test_paused_run_resumes_identically(self):
-        def staged(engine):
-            sim = Simulator(engine=engine)
-            sim.connect("a", "b", Link(1e7, 2_000_000, jitter_ns=3_000_000,
-                                       jitter_seed=2))
-            tr = make_transport("udp")
-            cfg = TransportConfig(kind="udp", udp_deadline_ns=4 * NS)
-            got = []
-            tr.create_receiver(sim, sim.node("b"), cfg, got.append)
-            tr.create_sender(sim, sim.node("a"), sim.node("b"),
-                             packetize(b"m" * 12_000, "a", txn=1, mtu=300),
-                             cfg).start()
-            mids = []
-            # Pause mid-flight several times, then drain.
-            for until in (2_500_000, 3_500_000, 5_000_000):
-                sim.run(until_ns=until)
-                mids.append((sim.now_ns, dict(sim.stats)))
-            sim.run()
-            return mids, sim.stats_digest(), [d.reassemble() for d in got]
+        assert _staged("per_packet") == _staged("batched")
 
-        assert staged("per_packet") == staged("batched")
+    @pytest.mark.parametrize("kind", ["udp", "mudp"])
+    def test_paused_run_resumes_identically_many_flights(self, kind):
+        # 24 senders' flights overlap, and under mudp lost packets bring
+        # NACK volleys and retransmission flights across the pauses.
+        assert _staged("per_packet", kind=kind, senders=24, loss=0.05) == \
+            _staged("batched", kind=kind, senders=24, loss=0.05)
+
+
+def _staged(engine, *, kind="udp", senders=1, loss=0.0):
+    """Run transfers from ``senders`` nodes to one receiver, pausing
+    mid-flight several times before draining; what each pause saw."""
+    sim = Simulator(engine=engine)
+    cfg = TransportConfig(kind=kind, udp_deadline_ns=4 * NS,
+                          timeout_ns=20_000_000)
+    tr = make_transport(kind)
+    got = []
+    tr.create_receiver(sim, sim.node("b"), cfg, got.append)
+    for c in range(senders):
+        addr = f"a{c}" if senders > 1 else "a"
+        lossy = (BernoulliLoss(p=loss, seed=c) if loss else NoLoss())
+        sim.connect(addr, "b", Link(1e7, 2_000_000 + 50_000 * c, lossy,
+                                    jitter_ns=3_000_000, jitter_seed=2 + c))
+        tr.create_sender(sim, sim.node(addr), sim.node("b"),
+                         packetize(b"m" * 12_000, addr, txn=1, mtu=300),
+                         cfg).start()
+    mids = []
+    # Pause mid-flight several times, then drain.
+    for until in (2_500_000, 3_500_000, 5_000_000):
+        sim.run(until_ns=until)
+        mids.append((sim.now_ns, dict(sim.stats)))
+    sim.run()
+    return mids, sim.stats_digest(), [d.reassemble() for d in got]
+
+
+# --------------------------------------------------------------------------
+# The flight pass's bounds, one at a time
+# --------------------------------------------------------------------------
+class _Recorder:
+    """A receiver with a bulk hook.  Each statically unsafe packet (non-DATA
+    or a transaction's last) is logged with what its transaction has stored
+    so far, or, for a transaction in ``watch_all``, with what every
+    transaction has.  ``decline(pkt)`` may return 0 or -1 to decline a due
+    packet; the hook also stops a run before such a packet."""
+
+    def __init__(self, sim, node, *, watch_all=(), decline=None):
+        self.sim = sim
+        self.watch_all = set(watch_all)
+        self.decline = decline
+        self.stored: dict = {}
+        self.log: list = []
+        self.one_by_one: list = []      # DATA stored through the handler
+        node.register(self.on_packet, bulk=self.ingest)
+
+    def _seen(self, key):
+        if key in self.watch_all:
+            return sorted((k, sorted(v)) for k, v in self.stored.items())
+        return sorted(self.stored.get(key, ()))
+
+    def on_packet(self, p):
+        key = (p.addr, p.txn)
+        if p.kind == PacketKind.DATA:
+            self.stored.setdefault(key, set()).add(p.seq)
+            if p.seq != p.total:
+                self.one_by_one.append(key + (p.seq,))
+        if p.kind != PacketKind.DATA or p.seq == p.total:
+            self.log.append((self.sim.now_ns, p.kind, key, p.seq,
+                             self._seen(key)))
+        return True
+
+    def ingest(self, pkts, i, j, arrivals):
+        if self.decline is not None:
+            c = self.decline(pkts[i])
+            if c is not None:
+                return c
+        k = i
+        while k < j:
+            p = pkts[k]
+            if (p.kind != PacketKind.DATA or p.seq == p.total
+                    or (self.decline is not None
+                        and self.decline(p) is not None)):
+                break
+            self.stored.setdefault((p.addr, p.txn), set()).add(p.seq)
+            k += 1
+        return k - i
+
+
+def _data(addr, txn, seqs, total):
+    return [make_data_packet(s, total, addr, bytes([s % 251]) * 200, txn=txn)
+            for s in seqs]
+
+
+class TestFlightPassBounds:
+    def _run(self, engine, sends, **recorder):
+        """``sends``: (node, link delay ns, packets) bursts, all sent at
+        time 0, each from its own node over its own link to ``SERVER``."""
+        sim = Simulator(engine=engine)
+        rec = _Recorder(sim, sim.node(SERVER), **recorder)
+        for node, delay, _ in sends:
+            sim.connect(node, SERVER, Link(1e7, delay))
+        for node, _, pkts in sends:
+            sim.node(node).send_burst(pkts, sim.node(SERVER))
+        sim.run()
+        return rec, sim
+
+    def _both(self, sends, **recorder):
+        ref, ref_sim = self._run("per_packet", sends, **recorder)
+        got, sim = self._run("batched", sends, **recorder)
+        assert got.log == ref.log
+        assert got.stored == ref.stored
+        assert sim.stats_digest() == ref_sim.stats_digest()
+        return got
+
+    def test_heterogeneous_flight_bounds_every_flight(self):
+        # A burst of two transactions (key None) whose second packet, the
+        # last of txn 6, arrives while txn 1's 40-packet flight is half in:
+        # txn 1 may not be ingested past it.
+        het = (_data("h", 5, [1], 4) + _data("h", 6, [1], 1)
+               + _data("h", 5, [2, 3, 4], 4))
+        got = self._both([("a", 1_000_000, _data("a", 1, range(1, 41), 60)),
+                          ("h", 3_000_000, het)],
+                         watch_all=[("h", 5), ("h", 6)])
+        at_bound = got.log[0]
+        assert at_bound[2] == ("h", 6)
+        seen = dict(at_bound[4])
+        assert 0 < len(seen[("a", 1)]) < 40
+        assert got.one_by_one == []     # bulk resumes past the bound
+
+    def test_second_flight_of_a_key_bounds_the_first(self):
+        # Flight B of the same (addr, txn), planned after A over another
+        # link, lands a NACK inside flight A's arrivals: A stops there, and
+        # goes on once the NACK is processed.
+        a = _data("a", 1, range(1, 41), 50) + _data("a", 1, [50], 50)
+        b = (_data("a", 1, [41], 50) + [make_nack(7, 50, "a", 1)]
+             + _data("a", 1, [42, 43], 50))
+        got = self._both([("a", 1_000_000, a), ("a2", 4_000_000, b)])
+        nack = got.log[0]
+        assert nack[1] == PacketKind.NACK
+        assert 1 < len(nack[4]) < 41
+        assert got.log[-1][3] == 50 and len(got.log[-1][4]) == 44
+        assert got.one_by_one == []     # bulk resumes past the NACK
+
+    @pytest.mark.parametrize("code", [0, -1])
+    def test_declined_hook_leaves_flight_deliverable(self, code):
+        # 0: every fifth packet is declined, then bulk resumes after it;
+        # -1: the flight is given up at its first packet past seq 20.
+        # Either way the declined packets stay seated and arrive one by one.
+        if code == 0:
+            def decline(p):
+                return 0 if p.seq % 5 == 0 else None
+        else:
+            def decline(p):
+                return -1 if p.seq > 20 else None
+        sends = [(f"c{c}", 1_000_000 + 300_000 * c,
+                  _data(f"c{c}", 1, range(1, 31), 31)) for c in range(8)]
+        got = self._both(sends, decline=decline)
+        assert all(len(v) == 30 for v in got.stored.values())
+        expect = sorted((node, 1, s) for node, _, _ in sends
+                        for s in range(1, 31) if decline(
+                            make_data_packet(s, 31, node, b"")) is not None)
+        assert sorted(got.one_by_one) == expect
 
 
 # --------------------------------------------------------------------------
