@@ -1,10 +1,10 @@
 """Where JAX keeps its persistent compilation cache.
 
 Entry points that compile for a device (``chip_smoke.py``,
-``examples/fleet_sim.py``, ``benchmarks/vmap_train.py``,
-``benchmarks/run.py``) call :func:`enable_compile_cache` first thing in
-``main``.  Importing :mod:`repro` never does: tests and library callers keep
-JAX's own defaults.
+``examples/fleet_sim.py``, ``bench/run.py``, ``bench/calibrate.py``) call
+:func:`enable_compile_cache` first thing in ``main``.  Importing
+:mod:`repro` never does: tests and library callers keep JAX's own
+defaults.
 
 * ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself, and nothing here
   overrides it.

@@ -35,7 +35,7 @@ from repro.core.simulator import Node, Simulator, Timer
 
 @dataclasses.dataclass
 class TxnStats:
-    """Per-transaction accounting surfaced to benchmarks/EXPERIMENTS.md."""
+    """Per-transaction accounting every sender exposes as ``stats``."""
 
     txn: int
     total_packets: int = 0

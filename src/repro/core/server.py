@@ -668,13 +668,14 @@ class ServerCore:
         version — or None when per-client encoding is required.
 
         A stateless downlink pipeline encodes the same model to the same
-        bytes for every client (deterministic, pinned by wire_bench's
-        determinism gate), so the N-client broadcast encodes **once** and
-        reuses the bytes.  The cache is refused outright when the downlink
-        pipeline is stateful (``PipelineCaps.stateful`` — e.g. ``ef|int8``
-        compensates each client separately, so sharing bytes would corrupt
-        per-client residuals) and invalidated on every ``global_params``
-        assignment, so a stale model can never be served.
+        bytes for every client (deterministic, pinned by
+        ``test_encode_identical_across_fresh_pipelines``), so the N-client
+        broadcast encodes **once** and reuses the bytes.  The cache is
+        refused outright when the downlink pipeline is stateful
+        (``PipelineCaps.stateful`` — e.g. ``ef|int8`` compensates each
+        client separately, so sharing bytes would corrupt per-client
+        residuals) and invalidated on every ``global_params`` assignment,
+        so a stale model can never be served.
         """
         if not self.cfg.batch_wire or self.downlink_pipeline.caps.stateful:
             return None

@@ -29,9 +29,9 @@ The first two engines are bit-for-bit identical: same keyed RNG draws (see
 ``repro.core.channel``), same tie-breaking (flights carry the tie numbers
 per-packet scheduling would have assigned), same stats, same final clock.
 ``tests/test_engine_equivalence.py`` pins this down for every registered
-transport; ``benchmarks/simcore.py`` measures the speedup.  The flow
-engine's statistical-equivalence contract is pinned by the seed-sweep
-harness in ``tests/statcheck.py`` + ``tests/test_flow_engine.py``.
+transport.  The flow engine's statistical-equivalence contract is pinned
+by the seed-sweep harness in ``tests/statcheck.py`` +
+``tests/test_flow_engine.py``.
 """
 
 from __future__ import annotations

@@ -32,8 +32,8 @@ publishes its hop names (``Topology.hops``) and
                      "edge->root: delta")
 
 Per-hop traffic is accounted by :meth:`Simulator.label_hop`
-(``sim.hop_bytes``), which is how ``benchmarks/topology_bench.py`` shows
-the root link shrinking ~linearly in aggregator count.
+(``sim.hop_bytes``), which is how ``test_hier_root_link_linear_in_cells``
+shows the root link growing ~linearly in aggregator count.
 
 See ``docs/TOPOLOGY.md`` for diagrams and guidance on when each topology
 wins.

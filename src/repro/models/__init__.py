@@ -1,11 +1,6 @@
-from repro.models.model import (abstract_params, abstract_train_state,
-                                batch_specs, cache_specs, init, init_cache,
-                                init_train_state, input_specs, loss_fn,
-                                make_decode_step, make_prefill_step,
-                                make_train_step, param_specs,
-                                train_state_specs)
+"""Client models and the LLM architectures behind them.
 
-__all__ = ["abstract_params", "abstract_train_state", "batch_specs",
-           "cache_specs", "init", "init_cache", "init_train_state",
-           "input_specs", "loss_fn", "make_decode_step", "make_prefill_step",
-           "make_train_step", "param_specs", "train_state_specs"]
+Import the module you need (``repro.models.mlp``, ``.lm_client``,
+``.model``); the package itself imports nothing, so loading one model
+does not load the others.
+"""

@@ -19,7 +19,8 @@ for that claim:
   O(n log n) over the pooled sample, no scipy dependency).
 
 What "equivalent" means per metric (the documented tolerances live with
-each test via :class:`Tolerance`; the methodology is docs/PERFORMANCE.md):
+each test via :class:`Tolerance`; the methodology is docs/PERFORMANCE.md,
+"What statistically equivalent means"):
 
 * ``mean``: ``|mean_a - mean_b| <= rtol * max(|a|,|b|) + atol`` OR within
   ``z_max`` pooled standard errors — the OR matters because a near-zero
@@ -197,7 +198,7 @@ def compare_sweeps(ref: dict[str, list[float]], flow: dict[str, list[float]],
 
 
 # --------------------------------------------------------------------------
-# Scenario builders (shared by tests and benchmarks)
+# Scenario builders (shared by the flow-engine tests)
 # --------------------------------------------------------------------------
 def transfer_metrics(engine: str, kind: str, seed: int, *,
                      loss_p: float = 0.1, bursty: bool = False,
@@ -250,8 +251,8 @@ def fleet_metrics(engine: str, transport: str, seed: int, *,
                   participation: float = 0.5, n_params: int = 512,
                   mode: str = "sync",
                   deadline_ns: int = 60 * NS) -> dict:
-    """One seeded fleet scenario (the fleet_scale benchmark's cell, sized
-    for sweeps): returns the tentpole's four gated metrics."""
+    """One seeded consensus fleet over ``transport``, sized for sweeps:
+    returns the metrics the fleet gates compare."""
     from repro.core import (ConsensusObjective, FLConfig, FleetConfig,
                             TransportConfig, build_fleet)
     fleet = FleetConfig(n_clients=n_clients, seed=seed,

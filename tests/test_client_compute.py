@@ -21,7 +21,8 @@ import pytest
 
 jax = pytest.importorskip("jax")
 
-from repro.core import FleetConfig, tracing                # noqa: E402
+from repro.core import (CohortSpec, FleetConfig, FLConfig,  # noqa: E402
+                        TransportConfig, build_fleet_training, tracing)
 from repro.core.client_compute import (BatchTrainer,       # noqa: E402
                                        ConsensusModel, TrainBackend,
                                        available_models,
@@ -48,6 +49,8 @@ from test_orchestrator_equivalence import EXPECTED, run_digest  # noqa: E402
 # durations) stay exactly equal.
 ULP_BOUND = 4
 FLOOR_ULPS = 4
+
+NS = 1_000_000_000
 
 
 def assert_ulp_close(a: np.ndarray, b: np.ndarray, bound: int = ULP_BOUND,
@@ -198,6 +201,33 @@ def test_fleet_parity_mlp_over_mudp(training_fleet):
     # And the model actually learns on its synthetic shards.
     m = bv.model
     assert m.accuracy(bv.system.global_params) > m.accuracy(m.init_params())
+
+
+def test_mnist_over_lossy_mudp_reaches_target_accuracy():
+    """The paper's claim on a learning workload: 16 clients on non-IID
+    Dirichlet(0.5) shards of the seeded synthetic MNIST, every link
+    dropping 10% of packets, reach 95% test accuracy within 20 rounds
+    because MUDP's NACK repair delivers the updates."""
+    lossy10 = CohortSpec(name="lossy10", up_rate_bps=(20e6, 20e6),
+                         down_up_ratio=2.0,
+                         delay_ns=(5_000_000, 20_000_000), jitter_frac=0.3,
+                         loss_p=(0.10, 0.10),
+                         train_time_ns=(200_000_000, 800_000_000))
+    fleet = FleetConfig(
+        n_clients=16, seed=0, cohorts={"lossy10": lossy10},
+        cohort_mix=(("lossy10", 1.0),), model="mlp", train_backend="vmap",
+        model_args={"hidden": 32, "batch_size": 32, "local_steps": 4,
+                    "shard_size": 256, "alpha": 0.5})
+    fl = FLConfig(aggregation="fedavg", transport=TransportConfig(
+        kind="mudp", timeout_ns=2 * NS, udp_deadline_ns=3 * NS))
+    build = build_fleet_training(fleet, fl)
+    model, system = build.model, build.system
+    assert model.data.source == "synthetic"
+    accuracy = []
+    while len(accuracy) < 20 and (not accuracy or accuracy[-1] < 0.95):
+        system.run_round()
+        accuracy.append(model.accuracy(system.global_params))
+    assert accuracy[-1] >= 0.95, accuracy
 
 
 def test_python_backend_attaches_no_trainer(training_fleet):

@@ -1,6 +1,6 @@
 """Adaptive transport control plane: telemetry, policies, renegotiation.
 
-Three contracts are gated here:
+Four contracts are gated here:
 
 1. **Pure add-on** — ``control="static"`` (set explicitly, not just
    defaulted) leaves every pinned orchestrator-equivalence digest
@@ -10,6 +10,9 @@ Three contracts are gated here:
    distributional version lives in tests/test_flow_engine.py).
 3. **Renegotiation mechanics** — the crc wire stage, encoder
    state-migration rules, decision dedup, and the adaptive ladder.
+4. **The ladder pays** — on a mixed cohort, adaptive control reaches the
+   target loss in less simulated time than any one tier pinned
+   fleet-wide.
 """
 
 from __future__ import annotations
@@ -304,10 +307,53 @@ def test_adaptive_fleet_renegotiates_and_converges():
     b = _build("batched", control="adaptive", n_clients=16, rounds=4)
     core = b.system.core
     assert sum(core.renegotiations.values()) > 0
-    # The sum of per-cohort counts in the benchmark equals the core's.
+    # Every client's health reaches the round result.
     last = b.system.history[-1]
     assert set(last.client_health) == {p.addr for p in b.profiles}
     assert b.model.loss(b.system.global_params) < 0.5
+
+
+def _sim_ns_to_target(control, tier):
+    """Simulated time at which 32 mixed-cohort clients over ``mudp+fec``
+    first bring the loss to 2% of its initial value within 16 rounds, or
+    None.  ``tier`` indexes ``DEFAULT_TIERS``: the static arm pins it
+    fleet-wide; the adaptive arm starts every client on it."""
+    t = DEFAULT_TIERS[tier]
+    fl = FLConfig(aggregation="fedavg", transport=TransportConfig(
+        kind="mudp+fec", uplink=t["uplink"], downlink="int8(1024)",
+        fec_block=t["fec_block"], fec_parity=t["fec_parity"],
+        timeout_ns=2 * NS, udp_deadline_ns=3 * NS))
+    fleet = FleetConfig(n_clients=32, seed=0, model="consensus",
+                        model_args={"n_params": 2048},
+                        round_deadline_ns=20 * NS, control=control)
+    b = build_fleet_training(fleet, fl)
+    target = 0.02 * b.model.loss(b.system.global_params)
+    crossed = []
+
+    def on_round(res, params):
+        if not crossed and b.model.loss(params) <= target:
+            crossed.append(b.sim.now_ns)
+    b.system.on_round_end = on_round
+    b.system.run_rounds(16)
+    return crossed[0] if crossed else None
+
+
+@pytest.fixture(scope="module")
+def adaptive_sim_ns():
+    return _sim_ns_to_target("adaptive", 1)
+
+
+@pytest.mark.parametrize("tier", [0, 1, 2], ids=["tier0", "tier1", "tier2"])
+def test_adaptive_reaches_target_before_static_tier(adaptive_sim_ns, tier):
+    """No single static tier fits a mixed fiber/lte/congested-edge cohort:
+    adaptive control, starting from the middle rung, reaches the target
+    in strictly less simulated time than each tier pinned fleet-wide (a
+    tier that never reaches it loses).  Its other half, static control
+    leaving the digests alone, is test_static_control_keeps_pinned_digests."""
+    static = _sim_ns_to_target("static", tier)
+    assert adaptive_sim_ns is not None
+    assert static is None or adaptive_sim_ns < static, (adaptive_sim_ns,
+                                                        static)
 
 
 def test_adaptive_identical_across_packet_engines():

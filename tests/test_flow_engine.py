@@ -5,13 +5,15 @@ Two layers of guarantees:
 * **Smokes (tier-1, unmarked)** — the flow engine drives every registered
   transport end to end, is deterministic per seed, is *additive* (the
   packet engines never see a flow adapter; their pinned digests are
-  untouched), and runs every topology and both scheduler modes.
+  untouched), runs every topology and both scheduler modes, and takes a
+  10,000-client hierarchical fleet through a round.
 * **Distributional gates (``-m stats``)** — multi-seed sweeps asserting
   the flow engine's metric distributions match the batched engine within
   the documented tolerances of ``tests/statcheck.py``, at the single-link
   level (per transport x loss regime) and the fleet level (per transport
   x topology).  The tolerance numbers here are the contract; the
-  methodology behind them is docs/PERFORMANCE.md.
+  methodology behind them is docs/PERFORMANCE.md, "What statistically
+  equivalent means".
 """
 
 import os
@@ -24,7 +26,7 @@ from repro.core.flow import FlowTransport, available_flow_models, maybe_flow
 from repro.core.simulator import ENGINES, Simulator
 
 sys.path.insert(0, os.path.dirname(__file__))
-from statcheck import (Tolerance, compare_sweeps,          # noqa: E402
+from statcheck import (NS, Tolerance, compare_sweeps,      # noqa: E402
                        fleet_metrics, sweep, transfer_metrics)
 
 SEEDS_LINK = range(100, 125)      # 25 seeds per single-link scenario
@@ -99,6 +101,15 @@ def test_flow_fleet_deterministic_per_seed():
     b = fleet_metrics("flow", "mudp", seed=9, n_clients=12, rounds=2,
                       n_params=128)
     assert a == b
+
+
+def test_flow_fleet_of_ten_thousand_clients_completes_a_round():
+    """Reach, not speed: 10,000 clients behind 32 edge aggregators, 60%
+    sampled, finish a round on the flow engine."""
+    m = fleet_metrics("flow", "mudp", seed=0, n_clients=10_000, rounds=1,
+                      topology="hier", cells=32, participation=0.6,
+                      n_params=2048, deadline_ns=10 * NS)
+    assert m["round_time_ns"] > 0 and m["bytes_on_wire"] > 0
 
 
 # --------------------------------------------------------------------------

@@ -289,3 +289,20 @@ def test_reference_copies_agree():
     assert body(ROOT / "bench/reference/lm.py") == body(
         ROOT / "src/repro/models/lm_reference.py")
     assert "repro" not in body(ROOT / "bench/reference/lm.py")
+
+
+def test_client_models_load_no_llm_shelf():
+    """``make_model("mlp")`` and ``make_model("lm")`` import their own
+    modules; the package imports nothing, so the architectures only
+    ``repro.models.model`` reaches stay unloaded."""
+    shelf = ["repro.models.model", "repro.models.encdec",
+             "repro.models.hymba", "repro.models.xlstm", "repro.optim"]
+    code = ("import sys\n"
+            "import repro.models.mlp, repro.models.lm_client\n"
+            f"print(sorted(set({shelf!r}) & set(sys.modules)))\n")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=str(ROOT / "src"))
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, env=env, cwd=ROOT, timeout=300)
+    assert r.returncode == 0, r.stderr[-3000:]
+    assert r.stdout.strip() == "[]", r.stdout

@@ -24,6 +24,8 @@ from repro.core.topology import (GossipTopology, HierSystem, StarTopology,
                                  register_topology, topology_hops)
 from repro.core.wire import WireError, parse_hop_specs
 
+NS = 1_000_000_000
+
 
 # Fleet construction and the params hash come from the shared
 # ``consensus_fleet`` / ``params_digest`` fixtures in conftest.py.
@@ -147,6 +149,57 @@ def test_hier_root_link_smaller_than_star(consensus_fleet):
     assert sum(sim_h.hop_bytes.values()) == sim_h.stats["bytes_sent"]
 
 
+def _losses_over_rounds(consensus_fleet, topology, *, rounds=3, **fleet_kw):
+    """64 clients, a 1,024-parameter objective and ``rounds`` rounds over
+    mudp: (sim, initial loss, the loss after each round)."""
+    cfg = FLConfig(transport=TransportConfig(
+        kind="mudp", timeout_ns=2 * NS, udp_deadline_ns=3 * NS))
+    obj, sim, system, _ = consensus_fleet(
+        topology, n=64, rounds=0, seed=0, obj_params=1024, obj_seed=0,
+        fl_cfg=cfg, **fleet_kw)
+    loss0 = obj.loss(system.global_params)
+    losses = []
+    system.on_round_end = lambda r, params: losses.append(obj.loss(params))
+    system.run_rounds(rounds)
+    return sim, loss0, losses
+
+
+def test_hier_root_link_linear_in_cells(consensus_fleet):
+    """The root link carries one merged update per cell: its bytes per
+    aggregator stay about constant from 2 to 8 cells, near the star's
+    server link scaled by cells / clients, at the star's final loss."""
+    star_sim, loss0, star_losses = _losses_over_rounds(consensus_fleet,
+                                                       "star")
+    star_link = star_sim.hop_bytes["client->server"]
+    per_cell = {}
+    for cells in (2, 4, 8):
+        sim, _, losses = _losses_over_rounds(consensus_fleet, "hier",
+                                             cells=cells)
+        root = sim.hop_bytes["edge->root"]
+        per_cell[cells] = root / cells
+        expect = star_link * cells / 64
+        assert 0.4 * expect <= root <= 2.5 * expect, (cells, root, expect)
+        assert abs(losses[-1] - star_losses[-1]) <= 0.02 * loss0
+    assert max(per_cell.values()) <= 1.6 * min(per_cell.values()), per_cell
+
+
+def test_hier_replay_bit_identical(consensus_fleet, params_digest):
+    """A 64-client fleet behind 4 edge aggregators, 60% sampled a round,
+    runs the same round twice from one seed."""
+    def one():
+        cfg = FLConfig(aggregation="fedavg", transport=TransportConfig(
+            kind="mudp", timeout_ns=2 * NS, udp_deadline_ns=3 * NS))
+        _, sim, system, results = consensus_fleet(
+            "hier", n=64, rounds=1, seed=0, obj_params=2048, obj_seed=0,
+            fl_cfg=cfg, cells=4, participation_fraction=0.6,
+            round_deadline_ns=10 * NS)
+        return ([dataclasses.asdict(r) for r in results],
+                dict(sim.hop_bytes), params_digest(system.global_params))
+    first = one()
+    assert first[0][0]["arrived"]
+    assert one() == first
+
+
 def test_hier_cell_assignment_round_robin(consensus_fleet):
     fleet = FleetConfig(n_clients=10, topology="hier", cells=3)
     assert [fleet.cell_of(i) for i in range(6)] == [0, 1, 2, 0, 1, 2]
@@ -238,6 +291,12 @@ def test_gossip_converges_and_is_deterministic(consensus_fleet,
     assert params_digest(s1.global_params) == params_digest(s2.global_params)
     initial = obj1.loss({"w": np.zeros(48, np.float32)})
     assert obj1.loss(s1.global_params) < 0.5 * initial
+
+
+def test_gossip_reaches_a_tenth_of_initial_loss(consensus_fleet):
+    _, loss0, losses = _losses_over_rounds(consensus_fleet, "gossip",
+                                           neighbors=4)
+    assert min(losses) <= 0.1 * loss0, (loss0, losses)
 
 
 def test_gossip_rejects_delta_pipelines():

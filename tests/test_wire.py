@@ -249,6 +249,43 @@ class TestNegotiation:
 
 
 # --------------------------------------------------------------------------
+# Encode determinism: the server's broadcast cache relies on it
+# --------------------------------------------------------------------------
+# The four legacy codecs as single-stage pipelines plus the compositions
+# the FL layer ships.
+DETERMINISM_SPECS = [
+    "raw",
+    "hex",
+    "int8(1024)",
+    "topk(0.01)",
+    "delta|ef|int8(1024)",
+    "delta|ef|topk(0.01)|int8(1024)",
+    "topk(0.01)|int8(256)",
+]
+
+
+@pytest.mark.parametrize("spec", DETERMINISM_SPECS)
+def test_encode_identical_across_fresh_pipelines(spec):
+    """Two independently constructed pipelines, each on fresh state, put
+    the same bytes on the wire; and a header-only decode equals the
+    out-of-band one (``test_decodes_from_header_alone`` holds that for
+    the specs it shares)."""
+    v = np.random.default_rng(0).standard_normal(250_000).astype(np.float32)
+    datas = []
+    for _ in range(2):
+        p = parse_pipeline(spec)
+        state = p.new_state()
+        if p.caps.delta_domain:
+            p.set_reference(state, np.zeros_like(v))
+        datas.append(p.encode(v, state))
+    assert datas[0] == datas[1]
+    if spec not in NEGOTIATION_SPECS:
+        negotiated, _ = decode_payload(datas[0])
+        oob = p.decode(datas[0], p.new_state())
+        assert negotiated.tobytes() == oob.tobytes()
+
+
+# --------------------------------------------------------------------------
 # Legacy bit-identity
 # --------------------------------------------------------------------------
 class TestLegacyMode:
