@@ -908,62 +908,71 @@ class ServerCore:
         return vec[:n_expected]
 
     def decode_vec_batch(self, datas: list[bytes],
-                         addrs: Optional[list] = None) -> np.ndarray:
-        """Batched :meth:`decode_vec` over uplink payloads: one ``(N,
-        n_params)`` float32 matrix, row i bit-identical to
+                         addrs: Optional[list] = None) -> list[np.ndarray]:
+        """Batched :meth:`decode_vec` over uplink payloads: one float32 row
+        of ``n_params`` per payload, row i bit-identical to
         ``decode_vec(datas[i])`` — including the per-item degradation
         contract: a malformed payload zero-fills *its* row and bumps
         ``decode_errors``; it never poisons the rest of the batch
         (``decode_payload_batch`` isolates it via per-item fallback).
         ``addrs`` (parallel to ``datas``, entries may be None) attributes
-        degradations to the right client's telemetry."""
-        n_expected = self.n_params
+        degradations to the right client's telemetry.
+
+        A row decoded to at least ``n_params`` float32 values is handed on
+        where the decode left it (a view of its matrix, no copy); only a
+        degraded or short row is materialised here, and counted as
+        ``aggregate.row_copies``.  Rows may be read-only."""
         pipeline = self.uplink_pipeline
-
-        def _degrade(i: int) -> None:
-            self.decode_errors += 1
-            if addrs is not None and addrs[i] is not None:
-                self.telemetry.observe_decode_error(
-                    addrs[i], now_ns=self.sim.now_ns)
-
-        out = np.zeros((len(datas), n_expected), dtype=np.float32)
+        decoded: list[Optional[np.ndarray]] = []
         if pipeline.self_describing:
-            for i, (vec, negotiated, err) in enumerate(
-                    wire_decode_payload_batch(datas)):
+            for vec, negotiated, err in wire_decode_payload_batch(datas):
                 if err is None and (negotiated.caps.delta_domain
                                     != pipeline.caps.delta_domain):
                     # Same policy refusal as decode_vec: a header whose
                     # delta-ness disagrees with the server's aggregation
                     # domain is degraded, not mis-aggregated.
                     vec = None
-                if vec is None:
-                    _degrade(i)
-                    continue
-                m = min(vec.size, n_expected)
-                out[i, :m] = vec[:m]
-            return out
-        for i, data in enumerate(datas):
-            try:
-                vec = pipeline.decode(data)
-            except WireDecodeError:
-                _degrade(i)
+                decoded.append(vec)
+        else:
+            for data in datas:
+                try:
+                    decoded.append(pipeline.decode(data))
+                except WireDecodeError:
+                    decoded.append(None)
+        n_expected = self.n_params
+        rows = []
+        copies = 0
+        for i, vec in enumerate(decoded):
+            if (vec is not None and vec.size >= n_expected
+                    and vec.dtype == np.float32):
+                rows.append(vec[:n_expected])
                 continue
-            m = min(vec.size, n_expected)
-            out[i, :m] = vec[:m]
-        return out
+            row = np.zeros(n_expected, dtype=np.float32)
+            if vec is None:
+                self.decode_errors += 1
+                if addrs is not None and addrs[i] is not None:
+                    self.telemetry.observe_decode_error(
+                        addrs[i], now_ns=self.sim.now_ns)
+            else:
+                m = min(vec.size, n_expected)
+                row[:m] = vec[:m]
+            rows.append(row)
+            copies += 1
+        tracing.count("aggregate.row_copies", copies)
+        return rows
 
     def _resolve_contribs(self, contribs: list) -> list:
         """Materialize any deferred (_PendingWire) updates in ``contribs``
         through one batched decode; pass decoded vectors through
-        untouched.  The stacked matrix rows stream straight into the
-        aggregation stack below, so a 256-client round does one vectorized
-        wire pass instead of 256 pipeline walks."""
+        untouched.  The decoded rows go straight to the fold below, so a
+        256-client round does one vectorized wire pass instead of 256
+        pipeline walks."""
         pending = [v for v, _ in contribs
                    if isinstance(v, _PendingWire) and v.vec is None]
         if pending:
-            mat = self.decode_vec_batch([p.data for p in pending],
-                                        [p.addr for p in pending])
-            for p, row in zip(pending, mat):
+            rows = self.decode_vec_batch([p.data for p in pending],
+                                         [p.addr for p in pending])
+            for p, row in zip(pending, rows):
                 p.vec = row
                 p.data = None     # the bytes are dead weight once decoded
         return [(v.vec if isinstance(v, _PendingWire) else v, w)
@@ -1018,11 +1027,20 @@ class ServerCore:
             return
         template = self.global_params
         if self.uplink_pipeline.caps.delta_domain:
-            vecs = [v for v, _ in contribs]
+            # The weighted mean in float32, term by term in contribution
+            # order from a zeroed accumulator: the rounding of
+            # ``sum(w * v for ...) / ws.sum()``, a -0.0 included, which
+            # the replay digests pin.  Contributions may be read-only
+            # views of the decode's matrix, so they are only read.
             ws = np.asarray([w for _, w in contribs], dtype=np.float32)
-            mean_delta = sum(w * v for v, w in zip(vecs, ws)) / ws.sum()
-            delta_tree = unflatten_from_vector(
-                mean_delta.astype(np.float32), template)
+            acc = np.zeros(self.n_params, dtype=np.float32)
+            term = np.empty_like(acc)
+            for (v, _), w in zip(contribs, ws):
+                np.multiply(w, v, out=term)
+                acc += term
+            acc /= ws.sum()
+            tracing.count("aggregate.rows", len(contribs))
+            delta_tree = unflatten_from_vector(acc, template)
             self.global_params = agg.apply_delta(
                 template, delta_tree, self.cfg.server_lr)
             return
