@@ -36,9 +36,25 @@ class ModelConfig:
     rope_yarn_beta_fast: float = 32.0
     rope_yarn_beta_slow: float = 1.0
     rope_yarn_attention_factor: float = 1.0
+    # -- latent attention (MLA, DeepSeek-V2/V3; 0 = GQA) ----------------------
+    # Queries (nope + rope dims a head) straight from the input; keys and
+    # values decompressed from an RMS-normed latent of ``kv_lora_rank``, and
+    # one RoPE key of ``qk_rope_head_dim`` shared by every head.
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_interleave: bool = False  # RoPE rotates adjacent pairs, not halves
     # -- MoE ----------------------------------------------------------------
     num_experts: int = 0
     num_experts_per_tok: int = 0
+    n_shared_experts: int = 0      # one SwiGLU of n * d_ff every token takes
+    first_k_dense_replace: int = 0  # leading layers with a dense SwiGLU
+    dense_d_ff: int = 0            # that SwiGLU's width (intermediate_size)
+    router_scoring: str = "softmax"  # softmax | sigmoid
+    router_bias: bool = False      # experts chosen on score + a bias
+    routed_scaling_factor: float = 1.0
+    rms_norm_eps: float = 1e-6
     # -- enc-dec (whisper) ---------------------------------------------------
     encoder_layers: int = 0
     encoder_seq: int = 0           # fixed frame count from the audio frontend

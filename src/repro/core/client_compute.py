@@ -204,6 +204,15 @@ def _lm_factory(n_clients: int, *, seed: int = 0, **kwargs) -> ClientModel:
 register_model("lm", _lm_factory)
 
 
+def _lm_mla_factory(n_clients: int, *, seed: int = 0,
+                    **kwargs) -> ClientModel:
+    from repro.models.lm_client import MLAClientModel
+    return MLAClientModel(n_clients, seed=seed, **kwargs)
+
+
+register_model("lm_mla", _lm_mla_factory)
+
+
 # --------------------------------------------------------------------------
 # Train backends
 # --------------------------------------------------------------------------

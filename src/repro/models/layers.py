@@ -185,7 +185,9 @@ def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     is O(S * chunk) instead of O(S^2). The XLA analogue of flash attention —
     the Pallas kernel (`repro.kernels.flash_attention`) is the TPU hot path
     for inference; this is the portable default for 32k+ prefill, and the
-    training path (the Pallas kernel has no backward pass).
+    training path (the Pallas kernel has no backward pass).  The values'
+    head size may differ from the queries' and keys' (MLA: 128 against
+    192).
 
     Each chunk is rematerialised in the backward pass (``jax.checkpoint``),
     so a gradient keeps O(S * chunk) live as well.  With a static ``band``
@@ -216,8 +218,8 @@ def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         return gqa_attention(qi, ki, vi, q_pos=pi, kv_pos=ti,
                              causal=causal, window=window)
 
-    out = jax.lax.map(one_chunk, (qc, pc, starts))  # (nq, B, chunk, H, hd)
-    return out.transpose(1, 0, 2, 3, 4).reshape(B, S, H, hd)
+    out = jax.lax.map(one_chunk, (qc, pc, starts))  # (nq, B, chunk, H, vd)
+    return out.transpose(1, 0, 2, 3, 4).reshape(B, S, H, v.shape[-1])
 
 
 def causal_prefix_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,

@@ -1,5 +1,5 @@
-"""A windowed/full-attention MoE decoder as a federated client: LoRA
-fine-tuning of a frozen base, one silo per client.
+"""MoE decoders as federated clients: LoRA fine-tuning of a frozen base,
+one silo per client.
 
 The deployment is cross-silo federated instruction tuning (FedIT,
 arXiv:2305.05644; OpenFedLLM, arXiv:2402.06954): every silo holds the same
@@ -9,29 +9,54 @@ them.  The flat parameter vector the fleet moves is therefore the adapters
 alone, and the base reaches the jitted step as an argument shared by the
 whole batch (:meth:`LMClientModel.frozen`), never as a constant.
 
-The architecture is the shelf's :class:`~repro.configs.base.ModelConfig`
-:data:`ARCH` (Mellum2-12B-A2.5B), cut in depth or shrunk for tests through
-``num_layers`` and ``overrides``.  One layer::
+The architecture is a shelf :class:`~repro.configs.base.ModelConfig`, cut
+in depth or shrunk for tests through ``num_layers`` and ``overrides``; one
+:class:`LMStep` picks each layer's attention and FFN block from it.
+``lm`` is :data:`ARCH` (Mellum2-12B-A2.5B)::
 
     h = x + Attn(RMSNorm(x))      GQA, RoPE; windowed or full by layer type
     y = h + MoE(RMSNorm(h))       softmax router, top-k renormalised,
                                   dropless SwiGLU experts
 
 Windowed layers use the default RoPE table, full layers YaRN's, with cos
-and sin scaled by the attention factor.  LoRA (rank ``lora_rank``, scale
-``lora_alpha / lora_rank``) sits on ``wq``, ``wk``, ``wv`` and ``wo``:
-``x W + s * (x A) B``.
+and sin scaled by the attention factor; LoRA on ``wq``, ``wk``, ``wv`` and
+``wo``.  ``lm_mla`` is :data:`MLA_ARCH` (Moonlight-16B-A3B, the
+DeepSeek-V3 block)::
+
+    h = x + MLA(RMSNorm(x))       q = x Wq (nope | rope dims a head);
+                                  c | k_pe = x Wkv_a, c RMS-normed;
+                                  k_nope | v = c Wkv_b; k_pe one RoPE key
+                                  every head shares; causal, scale
+                                  (nope + rope)**-0.5
+    y = h + FFN(RMSNorm(h))       the first ``first_k_dense_replace``
+                                  layers a dense SwiGLU; then sigmoid-
+                                  routed top-k experts (chosen on score +
+                                  bias, weighted by the unbiased scores,
+                                  renormalised, times the routed scale)
+                                  plus the shared experts' one SwiGLU
+
+with LoRA on ``q_proj``, ``kv_a_proj_with_mqa``, ``kv_b_proj`` and
+``o_proj``.  Its RoPE rotates adjacent pairs (``rope_interleave``): the
+base's RoPE columns of ``q_proj`` and ``kv_a_proj_with_mqa`` are
+de-interleaved once, when the base is drawn (:func:`rope_columns`; the B
+adapters' columns at use), so that the half-split rotation computes the
+same scores.  The leading dense layers run one by one, before one
+``lax.scan`` over the MoE layers.  LoRA (rank ``lora_rank``, scale
+``lora_alpha / lora_rank``) is ``x W + s * (x A) B``.
 
 Precision: base weights and activations bfloat16 with float32
-accumulation; adapters, their products (``precision="highest"``), their
-gradients and the SGD step float32.
+accumulation; a sigmoid router and its bias float32 at full precision;
+adapters, their products (``precision="highest"``), their gradients and
+the SGD step float32.
 
 Everything is drawn from the seed, on the device:
 
-* base leaf ``BASE_LEAVES[j]``, layer ``l``:
+* base leaf j of :func:`base_layout` (in its order), layer ``l`` (the
+  model's own layer number):
   ``normal(fold_in(fold_in(PRNGKey(seed), j), l), shape) * fan_in**-0.5``
   in float32, then bfloat16 (layer-less leaves use ``fold_in(PRNGKey(seed),
-  j)``); the norms' scales are ones;
+  j)``); the norms' scales are ones; ``e_score_correction_bias`` is
+  ``normal(...) * BIAS_STD``, kept float32;
 * the token stream of silo ``c`` at round ``r``, local step ``s``:
   ``u = uniform(fold_in(fold_in(fold_in(fold_in(PRNGKey(seed), STREAM), c),
   r), s), (seq_len + 1,))``, token ``(c * stride + floor(u**4 * 2 *
@@ -41,8 +66,8 @@ Everything is drawn from the seed, on the device:
 * the adapters' starting values (host, numpy): ``A`` uniform in
   ``±fan_in**-0.5`` from ``default_rng(seed)``, ``B`` zero.
 
-The plain reference of the same step is :mod:`repro.models.lm_reference`
-(and ``bench/reference/lm.py``, which imports nothing of this package).
+The plain references of the same step are ``bench/reference/lm.py`` and
+``bench/reference/lm_mla.py``; they import nothing of this package.
 """
 
 from __future__ import annotations
@@ -62,16 +87,16 @@ from repro.core.packetizer import flatten_to_vector, unflatten_from_vector
 from repro.models import layers as L
 from repro.models import transformer as T
 
-#: The shelf configuration the client trains.
+#: The shelf configurations the ``lm`` and ``lm_mla`` clients train.
 ARCH = "mellum2-12b-a2.5b"
-#: Base leaves in key order (``fold_in(PRNGKey(seed), index)``).
-BASE_LEAVES = ("embed", "unembed", "final_norm", "attn_norm", "mlp_norm",
-               "wq", "wk", "wv", "wo", "router", "we_gate", "we_up",
-               "we_down")
+MLA_ARCH = "moonlight-16b-a3b"
 #: ``fold_in`` tag of the token streams' key.
 STREAM = 1 << 20
-#: Projections that carry an adapter.
-LORA_TARGETS = ("wq", "wk", "wv", "wo")
+#: The selection bias leaf, and its draw's standard deviation: a trained
+#: buffer no checkpoint gives here, small beside the sigmoid scores' spread
+#: yet enough to change the chosen experts of some tokens.
+BIAS = "e_score_correction_bias"
+BIAS_STD = 0.02
 #: Queries per attention chunk and positions per chunk of the head's
 #: logits: at 8 silos x 4,096 positions a full layer's chunk of scores is
 #: 0.5 GB in float32, and the step fits one v5e beside its 7.6 GB base.
@@ -82,62 +107,109 @@ LOSS_CHUNK = 256
 FULL_GROUP = 512
 
 
-def base_layout(cfg: ModelConfig) -> dict[str, tuple[tuple, int, bool]]:
-    """``{leaf: (shape of one layer or of the whole leaf, fan_in, per
-    layer)}``; a fan-in of 0 marks a norm scale (ones)."""
-    d, hd, F, E = cfg.d_model, cfg.resolved_head_dim, cfg.d_ff, \
-        cfg.num_experts
-    q, kv = cfg.num_heads * hd, cfg.num_kv_heads * hd
-    return {
-        "embed": ((cfg.vocab_size, d), d, False),
-        "unembed": ((d, cfg.vocab_size), d, False),
-        "final_norm": ((d,), 0, False),
-        "attn_norm": ((d,), 0, True),
-        "mlp_norm": ((d,), 0, True),
-        "wq": ((d, q), d, True),
-        "wk": ((d, kv), d, True),
-        "wv": ((d, kv), d, True),
-        "wo": ((q, d), q, True),
-        "router": ((d, E), d, True),
-        "we_gate": ((E, F, d), d, True),        # experts (out, in)
-        "we_up": ((E, F, d), d, True),
-        "we_down": ((E, d, F), F, True),
-    }
+def base_layout(cfg: ModelConfig) -> dict[str, tuple[tuple, int,
+                                                     Optional[range]]]:
+    """``{leaf: (shape of one layer or of the whole leaf, fan_in, the
+    model layers it is stacked over or None)}``, in key order; a fan-in
+    of 0 marks a norm scale (ones)."""
+    d, V, n = cfg.d_model, cfg.vocab_size, cfg.num_layers
+    every, k0 = range(n), cfg.first_k_dense_replace
+    dense, sparse = range(k0), range(k0, n)
+    out = {"embed": ((V, d), d, None), "unembed": ((d, V), d, None),
+           "final_norm": ((d,), 0, None), "attn_norm": ((d,), 0, every),
+           "mlp_norm": ((d,), 0, every)}
+    if cfg.kv_lora_rank:
+        H, R = cfg.num_heads, cfg.kv_lora_rank
+        nope, rope, vd = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                          cfg.v_head_dim)
+        out.update({
+            "q_proj": ((d, H * (nope + rope)), d, every),
+            "kv_a_proj_with_mqa": ((d, R + rope), d, every),
+            "kv_a_layernorm": ((R,), 0, every),
+            "kv_b_proj": ((R, H * (nope + vd)), R, every),
+            "o_proj": ((H * vd, d), H * vd, every)})
+    else:
+        hd = cfg.resolved_head_dim
+        q, kv = cfg.num_heads * hd, cfg.num_kv_heads * hd
+        out.update({"wq": ((d, q), d, every), "wk": ((d, kv), d, every),
+                    "wv": ((d, kv), d, every), "wo": ((q, d), q, every)})
+    if k0:
+        F = cfg.dense_d_ff
+        out.update({"dense_gate": ((d, F), d, dense),
+                    "dense_up": ((d, F), d, dense),
+                    "dense_down": ((F, d), F, dense)})
+    E, F = cfg.num_experts, cfg.d_ff
+    out["router"] = ((d, E), d, sparse)
+    if cfg.router_bias:
+        out[BIAS] = ((E,), 1, sparse)
+    out.update({"we_gate": ((E, F, d), d, sparse),      # experts (out, in)
+                "we_up": ((E, F, d), d, sparse),
+                "we_down": ((E, d, F), F, sparse)})
+    if cfg.n_shared_experts:
+        F = cfg.n_shared_experts * cfg.d_ff
+        out.update({"shared_gate": ((d, F), d, sparse),
+                    "shared_up": ((d, F), d, sparse),
+                    "shared_down": ((F, d), F, sparse)})
+    return out
+
+
+def lora_targets(cfg: ModelConfig) -> tuple[str, ...]:
+    """The projections that carry an adapter."""
+    if cfg.kv_lora_rank:
+        return ("q_proj", "kv_a_proj_with_mqa", "kv_b_proj", "o_proj")
+    return ("wq", "wk", "wv", "wo")
 
 
 def adapter_layout(cfg: ModelConfig, rank: int) -> list[tuple[str, tuple]]:
     """Flat-vector layout of the adapters: ``(name, shape)`` in sorted
     name order (``tree_leaves`` of the dict), each stacked over layers."""
-    hd = cfg.resolved_head_dim
-    width = {"wq": (cfg.d_model, cfg.num_heads * hd),
-             "wk": (cfg.d_model, cfg.num_kv_heads * hd),
-             "wv": (cfg.d_model, cfg.num_kv_heads * hd),
-             "wo": (cfg.num_heads * hd, cfg.d_model)}
-    out = {}
-    for t in LORA_TARGETS:
-        fan_in, fan_out = width[t]
+    layout, out = base_layout(cfg), {}
+    for t in lora_targets(cfg):
+        fan_in, fan_out = layout[t][0]
         out[f"a_{t}"] = (cfg.num_layers, fan_in, rank)
         out[f"b_{t}"] = (cfg.num_layers, rank, fan_out)
     return sorted(out.items())
 
 
+def rope_columns(cfg: ModelConfig) -> dict[str, np.ndarray]:
+    """For RoPE on adjacent pairs: the output-column order of each
+    projection that makes RoPE dims, in which every head's RoPE dims
+    ``(0, 1), (2, 3), ...`` become ``(0, r/2), (1, r/2 + 1), ...``, the
+    half-split pairs :func:`~repro.models.layers.apply_rope` rotates.  Both
+    sides of a score are permuted alike, so the scores are unchanged."""
+    if not cfg.rope_interleave:
+        return {}
+    r, nope, R = cfg.qk_rope_head_dim, cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    pairs = np.concatenate([np.arange(0, r, 2), np.arange(1, r, 2)])
+    head = np.concatenate([np.arange(nope), nope + pairs])
+    return {"q_proj": np.concatenate([h * (nope + r) + head
+                                      for h in range(cfg.num_heads)]),
+            "kv_a_proj_with_mqa": np.concatenate([np.arange(R), R + pairs])}
+
+
 def init_base(cfg: ModelConfig, key: jax.Array) -> dict:
-    """The frozen base, bfloat16, drawn on the device leaf by leaf and
-    layer by layer (module docstring)."""
-    out, layout = {}, base_layout(cfg)
-    for j, name in enumerate(BASE_LEAVES):
-        shape, fan_in, per_layer = layout[name]
-        full = ((cfg.num_layers,) + shape) if per_layer else shape
+    """The frozen base, drawn on the device leaf by leaf and layer by
+    layer (module docstring); RoPE columns de-interleaved
+    (:func:`rope_columns`)."""
+    out, cols = {}, rope_columns(cfg)
+    for j, (name, (shape, fan_in, span)) in enumerate(
+            base_layout(cfg).items()):
+        full = shape if span is None else (len(span),) + shape
         if not fan_in:
             out[name] = jnp.ones(full, jnp.bfloat16)
             continue
         leaf_key = jax.random.fold_in(key, j)
-        if per_layer:
-            keys = jax.vmap(lambda i: jax.random.fold_in(leaf_key, i))(
-                jnp.arange(cfg.num_layers))
-            out[name] = _draw_layers(keys, shape, fan_in)
-        else:
+        if span is None:
             out[name] = _draw(leaf_key, shape, fan_in)
+            continue
+        keys = jax.vmap(lambda i: jax.random.fold_in(leaf_key, i))(
+            jnp.arange(span.start, span.stop))
+        if name == BIAS:
+            out[name] = _draw_bias(keys, shape)
+        else:
+            out[name] = _draw_layers(keys, shape, fan_in)
+        if name in cols:
+            out[name] = jnp.take(out[name], cols[name], axis=-1)
     return out
 
 
@@ -154,6 +226,12 @@ def _draw_layers(keys, shape, fan_in):
                                   ).astype(jnp.bfloat16), keys)
 
 
+@functools.partial(jax.jit, static_argnums=(1,))
+def _draw_bias(keys, shape):
+    return jax.lax.map(lambda k: jax.random.normal(k, shape, jnp.float32)
+                       * np.float32(BIAS_STD), keys)
+
+
 def stream_tokens(key, client, round_idx, step, seq_len: int, vocab: int,
                   n_clients: int) -> jax.Array:
     """``seq_len + 1`` tokens of one silo's stream (module docstring)."""
@@ -167,8 +245,8 @@ def stream_tokens(key, client, round_idx, step, seq_len: int, vocab: int,
 
 def rope_tables(cfg: ModelConfig, seq_len: int):
     """(cos, sin) of the windowed layers and of the full layers, each
-    (seq_len, head_dim / 2) float32."""
-    hd = cfg.resolved_head_dim
+    (seq_len, rotated dims / 2) float32."""
+    hd = cfg.qk_rope_head_dim or cfg.resolved_head_dim
     pos = jnp.arange(seq_len, dtype=jnp.int32)
     window = L.rope_cos_sin_freqs(pos, L.rope_inv_freq(hd, cfg.rope_theta))
     if not cfg.rope_yarn_factor:
@@ -178,6 +256,17 @@ def rope_tables(cfg: ModelConfig, seq_len: int):
         cfg.rope_yarn_beta_fast, cfg.rope_yarn_beta_slow),
         cfg.rope_yarn_attention_factor)
     return window, full
+
+
+def _swiglu(x, wg, wu, wd):
+    """A dense SwiGLU, x (T, d) with weights (d, F), (d, F), (F, d): the
+    products in x's dtype with float32 accumulation, as the experts'."""
+    f32 = jnp.float32
+    g = jnp.einsum("td,df->tf", x, wg, preferred_element_type=f32)
+    u = jnp.einsum("td,df->tf", x, wu, preferred_element_type=f32)
+    h = (jax.nn.silu(g) * u).astype(x.dtype)
+    return jnp.einsum("tf,fd->td", h, wd,
+                      preferred_element_type=f32).astype(x.dtype)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -240,40 +329,67 @@ class LMStep:
 
     def loss(self, ad: dict, base: dict, toks: jax.Array):
         """Σ over the batch of each silo's mean next-token loss (so each
-        row's gradient is its own silo's), and per-silo ``aux``."""
+        row's gradient is its own silo's), and per-silo ``aux``.  The
+        leading dense layers run one by one, the MoE layers as one scan."""
         cfg = self.cfg
         x = jnp.take(base["embed"], toks[:, :-1], axis=0)       # (K, T, d)
         tables = rope_tables(cfg, self.seq_len)
         flags = jnp.asarray(T.is_global_flags(cfg))
-        layers = {n: base[n] for n, (_, _, per_layer)
-                  in base_layout(cfg).items() if per_layer}
+        spans = {n: span for n, (_, _, span) in base_layout(cfg).items()
+                 if span is not None}
         lora = {n: jnp.swapaxes(a, 0, 1) for n, a in ad.items()}  # (L,K,..)
+        k0, n = cfg.first_k_dense_replace, cfg.num_layers
+
+        def at(lo, hi):
+            """The base leaves stacked over layers lo..hi-1, cut to them."""
+            return {m: base[m] if (lo, hi) == (s.start, s.stop)
+                    else base[m][lo - s.start:hi - s.start]
+                    for m, s in spans.items()
+                    if s.start <= lo and hi <= s.stop}
+
+        @jax.checkpoint
+        def dense_layer(x, p, a, full):
+            x = x + self._attention(self._norm(x, p["attn_norm"]), p, a,
+                                    full, tables)
+            return x + self._dense(self._norm(x, p["mlp_norm"]), p)
+
+        for i in range(k0):
+            x = dense_layer(x, {m: v[0] for m, v in at(i, i + 1).items()},
+                            {m: v[i] for m, v in lora.items()}, flags[i])
 
         @jax.checkpoint
         def layer(x, xs):
             p, a, full = xs
-            x = x + self._attention(L.rmsnorm(x, p["attn_norm"]), p, a,
+            x = x + self._attention(self._norm(x, p["attn_norm"]), p, a,
                                     full, tables)
-            y, counts = self._moe(L.rmsnorm(x, p["mlp_norm"]), p)
+            y, counts = self._moe(self._norm(x, p["mlp_norm"]), p)
             rows = counts.sum(-1)
             return x + y, (rows, counts.max(-1),
                            self.seq_len * cfg.num_experts_per_tok - rows)
 
+        if k0:
+            lora, flags = {m: v[k0:] for m, v in lora.items()}, flags[k0:]
         x, (rows, rows_max, dropped) = jax.lax.scan(
-            layer, x, (layers, lora, flags))
-        nll = self._head(L.rmsnorm(x, base["final_norm"]), base["unembed"],
+            layer, x, (at(k0, n), lora, flags))
+        nll = self._head(self._norm(x, base["final_norm"]), base["unembed"],
                          toks[:, 1:])
         return jnp.sum(nll), {"loss": nll, "rows": rows.sum(0),
                               "rows_max": rows_max.sum(0),
                               "dropped": dropped.sum(0)}
 
     # -- blocks ------------------------------------------------------------
-    def _proj(self, h, w, a, b):
+    def _norm(self, x, scale):
+        return L.rmsnorm(x, scale, self.cfg.rms_norm_eps)
+
+    def _proj(self, h, w, a, b, cols=None):
         """``h W + s * (h A) B``: the base product in bfloat16 with float32
-        accumulation, the adapter's in float32."""
+        accumulation, the adapter's in float32.  ``cols`` puts B's columns
+        in the order of W's (:func:`rope_columns`)."""
         base = jnp.einsum("ktd,do->kto", h, w,
                           preferred_element_type=jnp.float32)
         hp = jax.lax.Precision.HIGHEST
+        if cols is not None:
+            b = jnp.take(b, cols, axis=-1)
         low = jnp.einsum("ktd,kdr->ktr", h.astype(jnp.float32), a,
                          precision=hp)
         lora = jnp.einsum("ktr,kro->kto", low, b, precision=hp)
@@ -282,6 +398,8 @@ class LMStep:
 
     def _attention(self, h, p, a, full, tables):
         cfg = self.cfg
+        if cfg.kv_lora_rank:
+            return self._latent_attention(h, p, a, tables[1])
         K, S, _ = h.shape
         hd = cfg.resolved_head_dim
         q = self._proj(h, p["wq"], a["a_wq"], a["b_wq"]).reshape(
@@ -315,23 +433,73 @@ class LMStep:
         return self._proj(o.reshape(K, S, -1), p["wo"], a["a_wo"],
                           a["b_wo"])
 
+    def _latent_attention(self, h, p, a, table):
+        """MLA: queries from the input; k_nope and v decompressed from
+        the RMS-normed latent; the one RoPE key broadcast over the heads;
+        causal attention through the chunked causal-prefix runs."""
+        cfg = self.cfg
+        K, S, _ = h.shape
+        H, R = cfg.num_heads, cfg.kv_lora_rank
+        nope, rope, vd = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                          cfg.v_head_dim)
+        cols = rope_columns(cfg)
+
+        def proj(x, t):
+            return self._proj(x, p[t], a[f"a_{t}"], a[f"b_{t}"], cols.get(t))
+
+        q = proj(h, "q_proj").reshape(K, S, H, nope + rope)
+        ckv = proj(h, "kv_a_proj_with_mqa")
+        with jax.named_scope("lm.attn_mla"):
+            kv = proj(self._norm(ckv[..., :R], p["kv_a_layernorm"]),
+                      "kv_b_proj").reshape(K, S, H, nope + vd)
+            cos, sin = (t[None] for t in table)
+            q = jnp.concatenate(
+                [q[..., :nope], L.apply_rope(q[..., nope:], cos, sin)], -1)
+            k_pe = L.apply_rope(ckv[..., None, R:], cos, sin)  # (K,S,1,r)
+            k = jnp.concatenate(
+                [kv[..., :nope], jnp.broadcast_to(k_pe, (K, S, H, rope))], -1)
+            o = L.causal_prefix_attention(
+                q, k, kv[..., nope:], chunk=min(ATTN_CHUNK, S),
+                groups=max(1, S // FULL_GROUP))
+        return proj(o.reshape(K, S, H * vd), "o_proj")
+
+    def _dense(self, h, p):
+        """The leading layers' dense SwiGLU, one silo's sequence at a time
+        (its float32 (T, F) products would not fit for every silo at
+        once beside the base)."""
+        @jax.checkpoint
+        def silo(x):
+            with jax.named_scope("lm.ffn_dense"):
+                return _swiglu(x, p["dense_gate"], p["dense_up"],
+                               p["dense_down"])
+
+        return jax.lax.map(silo, h)
+
     def _moe(self, h, p):
         """Dropless top-k experts, one silo's sequence at a time, as a silo
         computes them on its own chip (each rematerialised in the backward
         pass: all 8 silos' token-expert rows at once would not fit beside
-        the base); returns the output and each silo's rows per expert
-        (K, E)."""
+        the base), plus the shared experts where the model has them;
+        returns the output and each silo's rows per routed expert (K, E)."""
         cfg = self.cfg
         E, topk = cfg.num_experts, cfg.num_experts_per_tok
+        route = {}
+        if cfg.router_scoring != "softmax":
+            route = dict(scoring=cfg.router_scoring, bias=p.get(BIAS),
+                         scale=cfg.routed_scaling_factor)
 
         @jax.checkpoint
         def silo(x):
             with jax.named_scope("lm.moe"):
-                top_w, top_i = T.moe_route(x, p["router"], topk)
+                top_w, top_i = T.moe_route(x, p["router"], topk, **route)
                 y = T.moe_dispatch(x, top_w, top_i, p["we_gate"],
                                    p["we_up"], p["we_down"],
                                    transposed=True)
                 counts = jnp.zeros((E,), jnp.int32).at[top_i].add(1)
+            if cfg.n_shared_experts:
+                with jax.named_scope("lm.moe_shared"):
+                    y = y + _swiglu(x, p["shared_gate"], p["shared_up"],
+                                    p["shared_down"])
             return y, counts
 
         return jax.lax.map(silo, h)
@@ -360,9 +528,10 @@ class LMStep:
 class LMClientModel(ClientModel):
     """LoRA fine-tuning of a frozen MoE decoder, one silo per client
     (module docstring).  ``num_layers`` and ``overrides`` (any
-    ``ModelConfig`` field) cut or shrink :data:`ARCH`."""
+    ``ModelConfig`` field) cut or shrink :attr:`arch`."""
 
     name = "lm"
+    arch = ARCH
     counters = ("train.tokens", "moe.rows", "moe.rows_max",
                 "moe.dropped_rows")
 
@@ -372,7 +541,7 @@ class LMClientModel(ClientModel):
                  local_steps: int = 2, lr: float = 0.05,
                  lora_rank: int = 16, lora_alpha: float = 32.0):
         super().__init__(n_clients, seed=seed)
-        cfg = get_config(ARCH)
+        cfg = get_config(self.arch)
         fields = dict(overrides or {})
         if num_layers is not None:
             fields["num_layers"] = int(num_layers)
@@ -387,10 +556,32 @@ class LMClientModel(ClientModel):
         self._eval: Optional[Callable] = None
 
     def reference_config(self) -> dict:
-        """The keys the plain reference reads (``lm_reference.sizes``):
-        the sizes under the published ``config.json`` names, the training
-        settings as ``model_args`` and the silo count."""
+        """The keys the plain reference reads (its ``sizes``): the sizes
+        under the published ``config.json`` names, the training settings
+        as ``model_args`` and the silo count."""
         cfg, step = self.cfg, self.step
+        out = {"num_hidden_layers": cfg.num_layers,
+               "hidden_size": cfg.d_model,
+               "num_attention_heads": cfg.num_heads,
+               "num_key_value_heads": cfg.num_kv_heads,
+               "num_experts_per_tok": cfg.num_experts_per_tok,
+               "moe_intermediate_size": cfg.d_ff,
+               "vocab_size": cfg.vocab_size,
+               "rms_norm_eps": cfg.rms_norm_eps,
+               "tie_word_embeddings": cfg.tie_embeddings}
+        if cfg.kv_lora_rank:
+            out.update(self._mla_keys())
+        else:
+            out.update(self._gqa_keys())
+        out.update({"n_clients": self.n_clients,
+                    "model_args": {"seq_len": step.seq_len,
+                                   "local_steps": step.local_steps,
+                                   "lr": step.lr, "lora_rank": step.rank,
+                                   "lora_alpha": step.alpha}})
+        return out
+
+    def _gqa_keys(self) -> dict:
+        cfg = self.cfg
         flags = T.is_global_flags(cfg)
         sliding = {"rope_type": "default", "rope_theta": cfg.rope_theta}
         full = dict(sliding)
@@ -402,28 +593,33 @@ class LMClientModel(ClientModel):
                     "beta_fast": cfg.rope_yarn_beta_fast,
                     "beta_slow": cfg.rope_yarn_beta_slow,
                     "attention_factor": cfg.rope_yarn_attention_factor}
-        return {
-            "num_hidden_layers": cfg.num_layers,
-            "hidden_size": cfg.d_model,
-            "num_attention_heads": cfg.num_heads,
-            "num_key_value_heads": cfg.num_kv_heads,
-            "head_dim": cfg.resolved_head_dim,
-            "num_experts": cfg.num_experts,
-            "num_experts_per_tok": cfg.num_experts_per_tok,
-            "moe_intermediate_size": cfg.d_ff,
-            "vocab_size": cfg.vocab_size,
-            "sliding_window": cfg.sliding_window,
-            "rms_norm_eps": 1e-6,
-            "layer_types": ["full_attention" if f else "sliding_attention"
-                            for f in flags],
-            "rope_parameters": {"full_attention": full,
-                                "sliding_attention": sliding},
-            "tie_word_embeddings": cfg.tie_embeddings,
-            "n_clients": self.n_clients,
-            "model_args": {"seq_len": step.seq_len,
-                           "local_steps": step.local_steps, "lr": step.lr,
-                           "lora_rank": step.rank,
-                           "lora_alpha": step.alpha}}
+        return {"head_dim": cfg.resolved_head_dim,
+                "num_experts": cfg.num_experts,
+                "sliding_window": cfg.sliding_window,
+                "layer_types": ["full_attention" if f
+                                else "sliding_attention" for f in flags],
+                "rope_parameters": {"full_attention": full,
+                                    "sliding_attention": sliding}}
+
+    def _mla_keys(self) -> dict:
+        """DeepSeek-V3's keys; no query low-rank, no expert groups (one
+        group, all of it chosen)."""
+        cfg = self.cfg
+        return {"kv_lora_rank": cfg.kv_lora_rank, "q_lora_rank": None,
+                "qk_nope_head_dim": cfg.qk_nope_head_dim,
+                "qk_rope_head_dim": cfg.qk_rope_head_dim,
+                "v_head_dim": cfg.v_head_dim,
+                "rope_theta": cfg.rope_theta,
+                "rope_interleave": cfg.rope_interleave,
+                "n_routed_experts": cfg.num_experts,
+                "n_shared_experts": cfg.n_shared_experts,
+                "first_k_dense_replace": cfg.first_k_dense_replace,
+                "intermediate_size": cfg.dense_d_ff,
+                "moe_layer_freq": 1,
+                "scoring_func": cfg.router_scoring,
+                "topk_method": "noaux_tc" if cfg.router_bias else "greedy",
+                "norm_topk_prob": True, "n_group": 1, "topk_group": 1,
+                "routed_scaling_factor": cfg.routed_scaling_factor}
 
     # -- ClientModel -------------------------------------------------------
     def frozen(self) -> dict:
@@ -491,3 +687,12 @@ class LMClientModel(ClientModel):
         tokens at a time in a loop that holds one dispatch's rows, where
         ``jax.vmap`` of the dispatch would hold all of them."""
         return self.step(stack, client_idx, round_idx, frozen)
+
+
+class MLAClientModel(LMClientModel):
+    """LoRA on the latent attention of a frozen DeepSeek-V3-type MoE
+    decoder, :data:`MLA_ARCH` (Moonlight-16B-A3B): the same client, step
+    and streams as ``lm`` (module docstring)."""
+
+    name = "lm_mla"
+    arch = MLA_ARCH

@@ -155,14 +155,34 @@ def _moe_block(x: jax.Array, p: dict, cfg: ModelConfig) -> jax.Array:
     return out
 
 
-def moe_route(x: jax.Array, router: jax.Array, k: int
-              ) -> tuple[jax.Array, jax.Array]:
-    """Softmax router, top-``k``, renormalised (``norm_topk_prob``).
-    x (T, d) -> weights (T, k) float32 and expert ids (T, k) int32."""
-    logits = jnp.einsum("td,de->te", x, router,
-                        preferred_element_type=jnp.float32)
-    top_w, top_i = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
-    return top_w / jnp.sum(top_w, axis=-1, keepdims=True), top_i
+def moe_route(x: jax.Array, router: jax.Array, k: int, *,
+              scoring: str = "softmax", bias: Optional[jax.Array] = None,
+              scale: float = 1.0) -> tuple[jax.Array, jax.Array]:
+    """Top-``k`` routing, renormalised (``norm_topk_prob``).
+    x (T, d) -> weights (T, k) float32 and expert ids (T, k) int32.
+
+    ``softmax``: softmax scores of the logits, top-k of them.  ``sigmoid``
+    (DeepSeek-V3's ``noaux_tc``): the logits in float32 at full precision,
+    sigmoid scores; the experts are chosen on score + ``bias`` (a
+    selection bias that never weights), weighted by their unbiased scores
+    over the sum of those (+1e-20) and times ``scale``
+    (``routed_scaling_factor``)."""
+    if scoring == "softmax":
+        logits = jnp.einsum("td,de->te", x, router,
+                            preferred_element_type=jnp.float32)
+        top_w, top_i = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+        return top_w / jnp.sum(top_w, axis=-1, keepdims=True), top_i
+    if scoring != "sigmoid":
+        raise ValueError(f"unknown router scoring {scoring!r}")
+    logits = jnp.einsum("td,de->te", x.astype(jnp.float32),
+                        router.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    choice = scores if bias is None else scores + bias.astype(jnp.float32)
+    _, top_i = jax.lax.top_k(choice, k)
+    top_w = jnp.take_along_axis(scores, top_i, axis=-1)
+    top_w = top_w / (jnp.sum(top_w, axis=-1, keepdims=True) + 1e-20)
+    return top_w * jnp.float32(scale), top_i
 
 
 def _ragged_dims(lhs_contract: int, rhs_contract: int, rhs_group):

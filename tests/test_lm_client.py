@@ -1,8 +1,11 @@
 """The ``lm`` client (LoRA on a frozen windowed/full-attention MoE decoder)
-at a tiny size on the CPU: against its plain reference, across the train
-backends, through the fleet, and the pieces it forced (YaRN tables, the
-windowed chunked attention, the frozen-argument contract)."""
+at a tiny size on the CPU: against its plain reference
+(``bench/reference/lm.py``, loaded by path), across the train backends,
+through the fleet, and the pieces it forced (YaRN tables, the windowed
+chunked attention, the frozen-argument contract)."""
 
+import hashlib
+import importlib.util
 import json
 import math
 import os
@@ -19,9 +22,12 @@ from repro.core import (FLConfig, FleetConfig, TransportConfig,
                         build_fleet_training, flatten_to_vector)
 from repro.core.client_compute import make_model, make_train_backend
 from repro.models import layers as L
-from repro.models import lm_reference as R
 
 ROOT = Path(__file__).resolve().parents[1]
+_spec = importlib.util.spec_from_file_location(
+    "ref_lm", ROOT / "bench/reference/lm.py")
+R = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(R)
 TINY = dict(num_layers=4, seq_len=64, local_steps=2, lr=0.02,
             overrides=dict(d_model=64, num_heads=4, num_kv_heads=2,
                            head_dim=16, d_ff=32, num_experts=8,
@@ -138,6 +144,31 @@ def test_counts_come_back_through_aux(vmap_out):
         assert a["moe.dropped_rows"] == 0
         # The busiest expert takes at least an even share, at most all.
         assert a["moe.rows"] / 8 <= a["moe.rows_max"] <= a["moe.rows"] / 2
+
+
+#: sha256 of the batched step's output and ``aux`` (sorted keys) at
+#: :data:`TINY`, and of the single step's (silo 1, round 1), as the program
+#: computed them before the step learnt other architectures: the Mellum2
+#: step is bit-identical to it.
+STEP_DIGESTS = {
+    "batch": "06cc4df2e1e2cad72980dc79462db15004070e70a12fde522ae25fe4bc1ae931",
+    "single": "7db1f14bf8f281c54d11f8b70a4eeeab4afd901d649a304f35729607c369b25a"}
+
+
+def _digest(new, aux):
+    h = hashlib.sha256(np.asarray(new).tobytes())
+    for k in sorted(aux):
+        h.update(k.encode())
+        h.update(np.asarray(aux[k]).tobytes())
+    return h.hexdigest()
+
+
+def test_step_is_bit_identical_to_its_pinned_digest(model, batch):
+    args = tuple(map(jnp.asarray, batch))
+    got = jax.jit(model.jax_train_batch)(*args, model.frozen())
+    one = jax.jit(model.jax_train)(args[0][1], jnp.int32(1), jnp.int32(1),
+                                   model.frozen())
+    assert {"batch": _digest(*got), "single": _digest(*one)} == STEP_DIGESTS
 
 
 def test_one_compile_serves_every_base(model, batch):
@@ -278,17 +309,6 @@ def test_bench_configuration_is_the_programs_model():
     assert R.Reference.base_bytes(config) == 2 * config["base_params"]
     flops = R.Reference.flops_per_update(config)
     assert 29e12 < flops < 31e12           # ~29.9 TFLOP a silo's round
-
-
-def test_reference_copies_agree():
-    """The benchmark's copy of the reference imports nothing of the
-    program and is this one past its docstring."""
-    def body(path):
-        text = path.read_text()
-        return text[text.index('"""', 3) + 3:]
-    assert body(ROOT / "bench/reference/lm.py") == body(
-        ROOT / "src/repro/models/lm_reference.py")
-    assert "repro" not in body(ROOT / "bench/reference/lm.py")
 
 
 def test_client_models_load_no_llm_shelf():

@@ -128,3 +128,26 @@ def test_expert_grouped_products_compile(sds, monkeypatch):
         sds((rows, d), jnp.bfloat16), sds((e, f, d), jnp.bfloat16),
         sds((e,), jnp.int32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("fan_in,fan_out", [(2048, 1408), (1408, 2048)],
+                         ids=["gate_up", "down"])
+def test_moonlight_expert_products_compile(sds, monkeypatch, fan_in,
+                                           fan_out):
+    """The ``lm_mla`` model's expert products at the Moonlight widths (one
+    silo's 4,096 tokens x top-6 rows, 64 experts of 2048 <-> 1408, a width
+    that only the 128 tile divides): forward and the input gradient lower
+    to the megablox kernels."""
+    from repro.models import transformer as T
+    monkeypatch.setattr(T, "_megablox",
+                        lambda *dims: all(d % 128 == 0 for d in dims))
+    rows, e = 4096 * 6, 64
+
+    def dx(x, w, sizes):
+        return jax.grad(lambda x: T.grouped_matmul(x, w, sizes, True)
+                        .sum())(x)
+    compiled = jax.jit(dx).lower(
+        sds((rows, fan_in), jnp.bfloat16),
+        sds((e, fan_out, fan_in), jnp.bfloat16),
+        sds((e,), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
